@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 import inspect
+import os
 import sys
 import time
 import traceback
@@ -44,6 +45,9 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown --only module(s) {unknown}; "
                  f"options: {','.join(MODULES)}")
+
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
     print("name,us_per_call,derived")
     failures = []

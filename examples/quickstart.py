@@ -3,8 +3,11 @@
     PYTHONPATH=src python examples/quickstart.py
 
 Synthetic flows -> windowed features -> Algorithm-1 partitioned training
--> range-marking rules -> data-plane engine inference (Pallas kernels in
-interpret mode) -> resource + recirculation reports.
+-> range-marking rules -> data-plane engine inference -> resource +
+recirculation reports.  Training and the resource models run on the
+host; the engine's walk (``impl="ref"``: dense jnp, no Pallas kernel)
+runs on JAX's default device — the TPU when one is attached, the CPU
+otherwise.
 """
 import numpy as np
 

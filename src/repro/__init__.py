@@ -1,7 +1,1 @@
-"""SpliDT reproduction: partitioned decision trees, TPU-native.
-
-Importing ``repro`` installs small forward-compat aliases for newer
-JAX APIs (see :mod:`repro._jax_compat`) so the same source runs on the
-pinned 0.4.x wheels and on current jax.
-"""
-from repro import _jax_compat as _jax_compat  # noqa: F401  (side effect)
+"""SpliDT reproduction: partitioned decision trees, TPU-native."""

@@ -131,7 +131,6 @@ class EngineOptions:
                      only read when the resolved backend is pallas)
     micro_batch      streaming/serving chunk size (flows per dispatch)
     inflight         streaming pipeline depth (chunks in flight)
-    donate           donate packet buffers to the walk (None = off-CPU)
     mesh             ``jax.sharding.Mesh`` to shard the flow axis over
     ===============  =====================================================
     """
@@ -142,7 +141,6 @@ class EngineOptions:
     block_b: int | None = None
     micro_batch: int = 4096
     inflight: int = 2
-    donate: bool | None = None
     mesh: "object | None" = None
 
     def __post_init__(self):
@@ -324,17 +322,9 @@ _WALK_STATIC = ("n_subtrees", "with_trace", "step", "compact",
 
 partition_walk = jax.jit(_partition_walk, static_argnames=_WALK_STATIC)
 
-# Donating the packet buffer lets back-to-back micro-batches reuse the
-# same device allocation (streaming path).  CPU can't donate host numpy
-# buffers usefully, so the streaming scheduler only picks this variant
-# off-CPU.
-partition_walk_donated = jax.jit(_partition_walk, static_argnames=_WALK_STATIC,
-                                 donate_argnums=(0,))
-
-# PR 1 names (step defaults to the dense jnp stage) — kept for callers
+# Older name (step defaults to the dense jnp stage) — kept for callers
 # that predate the backend layer.
 fused_partition_walk = partition_walk
-fused_partition_walk_donated = partition_walk_donated
 
 
 # ---------------------------------------------------------------------------
@@ -666,7 +656,6 @@ class Engine:
     def run_streaming(self, win_pkts: np.ndarray, *,
                       options: EngineOptions | None = None,
                       micro_batch=_UNSET,
-                      donate=_UNSET,
                       mesh=_UNSET,
                       impl=_UNSET,
                       inflight=_UNSET,
@@ -680,7 +669,7 @@ class Engine:
         deprecated shims for ``options=``.  See
         ``repro.serve.streaming``."""
         opt = _legacy_options(options, {
-            "micro_batch": micro_batch, "donate": donate, "mesh": mesh,
+            "micro_batch": micro_batch, "mesh": mesh,
             "impl": impl, "inflight": inflight, "compact": compact})
         from repro.serve.streaming import run_streaming
         return run_streaming(self, win_pkts, options=opt)

@@ -15,7 +15,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -66,11 +65,11 @@ def pipeline_forward(
         # the replicated out_spec is truthful on every device
         return jax.lax.psum(outs, stage_axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
 
 def make_stage_mesh(n_stages: int, data: int = 1):

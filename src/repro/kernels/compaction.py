@@ -92,8 +92,8 @@ def compact_perm(done: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     ``argsort`` on the ``done`` flags (stable: False < True) moves every
     surviving flow into the prefix while preserving original order; the
     prefix length is ``B - sum(done)``.  Both are device values — no
-    host sync, so compaction composes with the fully-jitted walk,
-    ``shard_map`` (each shard counts its own survivors) and donation.
+    host sync, so compaction composes with the fully-jitted walk and
+    ``shard_map`` (each shard counts its own survivors).
     """
     B = done.shape[0]
     perm = jnp.argsort(done, stable=True)

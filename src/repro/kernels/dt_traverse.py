@@ -16,8 +16,19 @@ threshold and leaf tables into VMEM alongside its flow block — the TPU
 analogue of the switch activating one subtree's MAT entries per
 pipeline pass.
 
-VMEM per step: regs (Bb, k) + thresholds (k, T) + leaf tables (L, k) x2
-+ actions (L,) — a few tens of KB at Bb=128, k<=8, T,L<=64.
+Layout: the kernel walks the k slots in a static loop, so every
+intermediate is a 2-D ``(Bb, T)`` or ``(Bb, L)`` tile with the
+threshold/leaf axis on the 128-wide lanes (k on the lanes would pad
+every tile 128/k-fold).  The wrapper lays the leaf tables out slot-major
+``(S, k, L)`` and the per-leaf rows as ``(S, 1, L)``, so every block's
+last two dimensions equal the array's, as Mosaic's (8, 128) tiling rule
+requires.  The relayout is a transpose of the ``S * L * k`` leaf tables
+per call, inside the same jit.
+
+VMEM per step (double-buffered inputs plus a handful of live tiles):
+at Bb=128 and T = L = 1024, the top of the DSE range (k <= 6, subtree
+depth <= 10), each ``(Bb, T)`` / ``(Bb, L)`` tile is 512 KiB — a few
+MiB in all, inside the 16 MiB scoped-VMEM default of a TPU v5e.
 """
 from __future__ import annotations
 
@@ -34,24 +45,22 @@ BLOCK_B = 128
 def _kernel(block_sid_ref, regs_ref, thr_ref, lo_ref, hi_ref, act_ref,
             valid_ref, out_ref):
     del block_sid_ref  # consumed by the index maps
-    regs = regs_ref[...]                       # (Bb, k)
-    thr = thr_ref[0]                           # (k, T)
-    lo = lo_ref[0]                             # (L, k)
-    hi = hi_ref[0]                             # (L, k)
-    act = act_ref[0]                           # (L,)
-    lvalid = valid_ref[0]                      # (L,)
-
-    marks = (regs[:, :, None] > thr[None]).sum(axis=2).astype(jnp.int32)
-    m = marks[:, None, :]                      # (Bb, 1, k)
-    hit = (m >= lo[None]) & (m <= hi[None])    # (Bb, L, k)
-    hit = hit.all(axis=2) & (lvalid[None] > 0)  # (Bb, L)
-    Bb, L = hit.shape
-    lidx = jax.lax.broadcasted_iota(jnp.int32, (Bb, L), 1)
-    first = jnp.min(jnp.where(hit, lidx, L), axis=1)
-    sel = (lidx == first[:, None]) & hit
-    action = (act[None] * sel).sum(axis=1)
-    found = hit.any(axis=1)
-    out_ref[...] = jnp.where(found, action, -1)[:, None].astype(jnp.int32)
+    k = thr_ref.shape[1]
+    L = lo_ref.shape[2]
+    hit = valid_ref[0] > 0                                 # (1, L)
+    for j in range(k):
+        reg = regs_ref[:, j:j + 1]                         # (Bb, 1)
+        thr = thr_ref[0, j:j + 1, :]                       # (1, T)
+        # integer count: exact in any reduction order
+        mark = (reg > thr).astype(jnp.int32).sum(axis=1, keepdims=True)
+        hit = (hit & (mark >= lo_ref[0, j:j + 1, :])
+               & (mark <= hi_ref[0, j:j + 1, :]))          # (Bb, L)
+    lidx = jax.lax.broadcasted_iota(jnp.int32, hit.shape, 1)
+    first = jnp.min(jnp.where(hit, lidx, L), axis=1, keepdims=True)
+    # priority encode: the first hit's action; no hit selects nothing
+    # and leaves the -1 sentinel
+    out_ref[...] = jnp.max(jnp.where(lidx == first, act_ref[0], -1),
+                           axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_b"))
@@ -80,10 +89,10 @@ def dt_traverse_pallas(
         in_specs=[
             pl.BlockSpec((bb, k), lambda i, bs: (i, 0)),
             pl.BlockSpec((1, k, T), lambda i, bs: (bs[i], 0, 0)),
-            pl.BlockSpec((1, L, k), lambda i, bs: (bs[i], 0, 0)),
-            pl.BlockSpec((1, L, k), lambda i, bs: (bs[i], 0, 0)),
-            pl.BlockSpec((1, L), lambda i, bs: (bs[i], 0)),
-            pl.BlockSpec((1, L), lambda i, bs: (bs[i], 0)),
+            pl.BlockSpec((1, k, L), lambda i, bs: (bs[i], 0, 0)),
+            pl.BlockSpec((1, k, L), lambda i, bs: (bs[i], 0, 0)),
+            pl.BlockSpec((1, 1, L), lambda i, bs: (bs[i], 0, 0)),
+            pl.BlockSpec((1, 1, L), lambda i, bs: (bs[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((bb, 1), lambda i, bs: (i, 0)),
     )
@@ -92,4 +101,6 @@ def dt_traverse_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb * bb, 1), jnp.int32),
         interpret=interpret,
-    )(block_sid, regs, thresholds, leaf_lo, leaf_hi, leaf_action, leaf_valid)
+    )(block_sid, regs, thresholds,
+      jnp.swapaxes(leaf_lo, 1, 2), jnp.swapaxes(leaf_hi, 1, 2),
+      leaf_action[:, None, :], leaf_valid[:, None, :])

@@ -10,9 +10,10 @@ Grid: one step per flow block.  Per-flow op/field/pred rows are gathered
 from the SID-indexed operator-selection tables *outside* the kernel
 (tiny XLA gathers); the kernel does the O(B * W * k) reduction work.
 
-Layout: flow blocks of ``BLOCK_B`` rows; the packet window (W, up to a
-few hundred) and the k slots live fully in VMEM
-(BLOCK_B * W * 6 * 4B ~= 0.2 MB at BLOCK_B=128, W=64).
+Layout: the wrapper hands the kernel field-major packets ``(F, B, W)``
+in blocks of up to ``BLOCK_B`` flow rows (fewer for long windows, see
+:func:`window_block_rows`); each field is then a 2-D ``(Bb, W)`` tile
+with the window on the lanes, and the kernel loops over the k slots.
 """
 from __future__ import annotations
 
@@ -27,6 +28,13 @@ from repro.kernels.dispatch import pad_axis0, round_up
 from repro.kernels.ref import ordered_wsum
 
 BLOCK_B = 128
+#: Budget on ``rows * W`` of one window-kernel block (see
+#: :func:`window_block_rows`).
+WINDOW_VMEM_ELEMS = 8192
+
+_FLAG_PREDS = ((F.PRED_SYN, F.FLAG_SYN), (F.PRED_ACK, F.FLAG_ACK),
+               (F.PRED_FIN, F.FLAG_FIN), (F.PRED_RST, F.FLAG_RST),
+               (F.PRED_PSH, F.FLAG_PSH), (F.PRED_URG, F.FLAG_URG))
 
 
 def _packet_mask_val(pkt, pred, field, k):
@@ -42,9 +50,7 @@ def _packet_mask_val(pkt, pred, field, k):
     mask = v & (pred == F.PRED_TRUE)
     mask |= v & (pred == F.PRED_FWD) & (direc[:, None] == 0)
     mask |= v & (pred == F.PRED_BWD) & (direc[:, None] == 1)
-    for code, bit in ((F.PRED_SYN, F.FLAG_SYN), (F.PRED_ACK, F.FLAG_ACK),
-                      (F.PRED_FIN, F.FLAG_FIN), (F.PRED_RST, F.FLAG_RST),
-                      (F.PRED_PSH, F.FLAG_PSH), (F.PRED_URG, F.FLAG_URG)):
+    for code, bit in _FLAG_PREDS:
         mask |= v & (pred == code) & ((flags[:, None] & bit) > 0)
     val = jnp.zeros((n, k), jnp.float32)
     for c in range(F.PKT_NFIELDS):
@@ -53,62 +59,76 @@ def _packet_mask_val(pkt, pred, field, k):
 
 
 def _kernel(pkts_ref, op_ref, field_ref, pred_ref, init_ref, out_ref):
-    pkts = pkts_ref[...]                                   # (Bb, W, F)
-    op = op_ref[...]                                       # (Bb, k)
-    field = field_ref[...]
-    pred = pred_ref[...]
-    init = init_ref[...]
-    Bb, W, _ = pkts.shape
-    k = op.shape[1]
-
-    valid = pkts[..., F.PKT_VALID] > 0                     # (Bb, W)
-    direc = pkts[..., F.PKT_DIR]
-    flags = pkts[..., F.PKT_FLAGS].astype(jnp.int32)
-
-    p = pred[:, None, :]                                   # (Bb, 1, k)
-    v = valid[:, :, None]
-    mask = v & (p == F.PRED_TRUE)
-    mask |= v & (p == F.PRED_FWD) & (direc[:, :, None] == 0)
-    mask |= v & (p == F.PRED_BWD) & (direc[:, :, None] == 1)
-    for code, bit in ((F.PRED_SYN, F.FLAG_SYN), (F.PRED_ACK, F.FLAG_ACK),
-                      (F.PRED_FIN, F.FLAG_FIN), (F.PRED_RST, F.FLAG_RST),
-                      (F.PRED_PSH, F.FLAG_PSH), (F.PRED_URG, F.FLAG_URG)):
-        mask |= v & (p == code) & ((flags[:, :, None] & bit) > 0)
-
-    fsel = field[:, None, :]
-    val = jnp.zeros((Bb, W, k), jnp.float32)
-    for c in range(F.PKT_NFIELDS):
-        val = jnp.where(fsel == c, pkts[..., c][:, :, None], val)
-
-    mf = mask.astype(jnp.float32)
-    # same canonical left-to-right order as the jnp reference, so the
-    # kernel's registers are bit-identical to training-time features
-    count = ordered_wsum(mf)
-    total = ordered_wsum(val * mf)
-    sumsq = ordered_wsum(val * val * mf)
+    """One flow block: ``pkts_ref`` (F, Bb, W) field-major, the per-slot
+    rows (Bb, k).  A static loop over the k slots keeps every
+    intermediate a 2-D (Bb, W) tile with the window on the lanes —
+    Mosaic cannot lay out the 3-D (Bb, W, k) bool broadcasts of the
+    reference."""
+    valid = pkts_ref[F.PKT_VALID] > 0                      # (Bb, W)
+    direc = pkts_ref[F.PKT_DIR]
+    flags = pkts_ref[F.PKT_FLAGS].astype(jnp.int32)
+    Bb, W = valid.shape
+    pos = jax.lax.broadcasted_iota(jnp.int32, (Bb, W), 1)
     neg_big = jnp.float32(-3.4e38)
     pos_big = jnp.float32(3.4e38)
-    mx = jnp.max(jnp.where(mask, val, neg_big), axis=1)
-    mx = jnp.where(mx <= neg_big, 0.0, mx)
-    mn = jnp.min(jnp.where(mask, val, pos_big), axis=1)
-    mn = jnp.where(mn >= pos_big, init, mn)
+    for j in range(op_ref.shape[1]):
+        op = op_ref[:, j:j + 1]                            # (Bb, 1)
+        p = pred_ref[:, j:j + 1]
+        fsel = field_ref[:, j:j + 1]
+        mask = valid & (p == F.PRED_TRUE)
+        mask |= valid & (p == F.PRED_FWD) & (direc == 0)
+        mask |= valid & (p == F.PRED_BWD) & (direc == 1)
+        for code, bit in _FLAG_PREDS:
+            mask |= valid & (p == code) & ((flags & bit) > 0)
+        val = jnp.zeros((Bb, W), jnp.float32)
+        for c in range(F.PKT_NFIELDS):
+            val = jnp.where(fsel == c, pkts_ref[c], val)
 
-    pos = jax.lax.broadcasted_iota(jnp.int32, (Bb, W, k), 1)
-    first_i = jnp.min(jnp.where(mask, pos, W), axis=1)     # (Bb, k)
-    last_i = jnp.max(jnp.where(mask, pos, -1), axis=1)
-    # branchless select-at-index: one-hot dot over the window axis
-    first = (val * ((pos == first_i[:, None, :]) & mask)).sum(axis=1)
-    last = (val * ((pos == last_i[:, None, :]) & mask)).sum(axis=1)
+        mf = mask.astype(jnp.float32)
+        # same canonical left-to-right order as the jnp reference, so the
+        # kernel's registers are bit-identical to training-time features
+        count = ordered_wsum(mf, keepdims=True)
+        total = ordered_wsum(val * mf, keepdims=True)
+        sumsq = ordered_wsum(val * val * mf, keepdims=True)
+        mx = jnp.max(jnp.where(mask, val, neg_big), axis=1, keepdims=True)
+        mx = jnp.where(mx <= neg_big, 0.0, mx)
+        mn = jnp.min(jnp.where(mask, val, pos_big), axis=1, keepdims=True)
+        mn = jnp.where(mn >= pos_big, init_ref[:, j:j + 1], mn)
 
-    out = jnp.zeros((Bb, k), jnp.float32)
-    out = jnp.where(op == F.OP_COUNT, count, out)
-    out = jnp.where(op == F.OP_SUM, total, out)
-    out = jnp.where(op == F.OP_MAX, mx, out)
-    out = jnp.where(op == F.OP_MIN, mn, out)
-    out = jnp.where(op == F.OP_LAST, last, out)
-    out = jnp.where(op == F.OP_FIRST, first, out)
-    out = jnp.where(op == F.OP_SUMSQ, sumsq, out)
-    out_ref[...] = out
+        first_i = jnp.min(jnp.where(mask, pos, W), axis=1, keepdims=True)
+        last_i = jnp.max(jnp.where(mask, pos, -1), axis=1, keepdims=True)
+        # branchless select-at-index: the one packet at the index (no
+        # masked packet selects nothing and falls back to 0.0)
+        first = jnp.max(jnp.where(pos == first_i, val, neg_big), axis=1,
+                        keepdims=True)
+        first = jnp.where(first_i < W, first, 0.0)
+        last = jnp.max(jnp.where(pos == last_i, val, neg_big), axis=1,
+                       keepdims=True)
+        last = jnp.where(last_i >= 0, last, 0.0)
+
+        out = jnp.zeros((Bb, 1), jnp.float32)
+        out = jnp.where(op == F.OP_COUNT, count, out)
+        out = jnp.where(op == F.OP_SUM, total, out)
+        out = jnp.where(op == F.OP_MAX, mx, out)
+        out = jnp.where(op == F.OP_MIN, mn, out)
+        out = jnp.where(op == F.OP_LAST, last, out)
+        out = jnp.where(op == F.OP_FIRST, first, out)
+        out = jnp.where(op == F.OP_SUMSQ, sumsq, out)
+        out_ref[:, j:j + 1] = out
+
+
+def window_block_rows(W: int, block_b: int = BLOCK_B) -> int:
+    """Flow rows per window-kernel block: ``block_b``, halved until a
+    block's ``rows * W`` fits :data:`WINDOW_VMEM_ELEMS`.
+
+    The unrolled ``ordered_wsum`` chains keep one lane-padded column
+    live per window position, so scoped VMEM grows with ``rows * W``
+    (a v5e compile refused 128 rows at W=256: 40 MiB of a 16 MiB
+    limit).  Never below 8 rows, the sublane tile."""
+    bb = block_b
+    while bb > 8 and bb * W > WINDOW_VMEM_ELEMS:
+        bb = max(8, bb // 16 * 8)
+    return bb
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_b"))
@@ -124,27 +144,22 @@ def feature_window_pallas(
 ) -> jnp.ndarray:
     B, W, nf = pkts.shape
     k = slot_op.shape[1]
-    bb = min(block_b, B)
+    bb = min(window_block_rows(W, block_b), B)
     Bp = round_up(B, bb)
     if Bp != B:
         pkts, slot_op, slot_field, slot_pred, slot_init = (
             pad_axis0(x, Bp)
             for x in (pkts, slot_op, slot_field, slot_pred, slot_init))
-    grid = (Bp // bb,)
+    row = pl.BlockSpec((bb, k), lambda i: (i, 0))
     out = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, W, nf), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bb, k), lambda i: (i, 0)),
-            pl.BlockSpec((bb, k), lambda i: (i, 0)),
-            pl.BlockSpec((bb, k), lambda i: (i, 0)),
-            pl.BlockSpec((bb, k), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, k), lambda i: (i, 0)),
+        grid=(Bp // bb,),
+        in_specs=[pl.BlockSpec((nf, bb, W), lambda i: (0, i, 0)),
+                  row, row, row, row],
+        out_specs=row,
         out_shape=jax.ShapeDtypeStruct((Bp, k), jnp.float32),
         interpret=interpret,
-    )(pkts, slot_op, slot_field, slot_pred, slot_init)
+    )(jnp.moveaxis(pkts, 2, 0), slot_op, slot_field, slot_pred, slot_init)
     return out[:B]
 
 

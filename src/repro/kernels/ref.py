@@ -18,7 +18,7 @@ from repro.core import features as F
 _UNROLL_W = 256
 
 
-def ordered_wsum(x: jnp.ndarray) -> jnp.ndarray:
+def ordered_wsum(x: jnp.ndarray, *, keepdims: bool = False) -> jnp.ndarray:
     """Strict left-to-right f32 sum over the window axis (axis 1).
 
     The canonical reduction order shared by the offline feature pipeline
@@ -27,16 +27,21 @@ def ordered_wsum(x: jnp.ndarray) -> jnp.ndarray:
     pick a shape-dependent summation tree, and a last-ulp difference can
     flip a flow sitting exactly on a learned threshold; chaining the
     adds pins the order for every (B, W, k) shape, so training-time
-    features and runtime registers agree bit-exactly.
+    features and runtime registers agree bit-exactly.  ``keepdims``
+    keeps the summed axis at size 1 (the Pallas kernel sums 2-D
+    ``(Bb, W)`` tiles into ``(Bb, 1)`` columns).
     """
     W = x.shape[1]
     if W <= _UNROLL_W:          # trace-time unroll: W-1 chained adds
-        acc = x[:, 0]
+        acc = x[:, 0:1]
         for w in range(1, W):
-            acc = acc + x[:, w]
-        return acc
-    return jax.lax.fori_loop(    # same left-to-right order, rolled
-        1, W, lambda w, acc: acc + x[:, w], x[:, 0])
+            acc = acc + x[:, w:w + 1]
+    else:                       # same left-to-right order, rolled
+        acc = jax.lax.fori_loop(
+            1, W,
+            lambda w, acc: acc + jax.lax.dynamic_slice_in_dim(x, w, 1, 1),
+            x[:, 0:1])
+    return acc if keepdims else acc[:, 0]
 
 
 def _pred_mask(pkts: jnp.ndarray, pred: jnp.ndarray) -> jnp.ndarray:

@@ -19,8 +19,9 @@ emits), and pushes each chunk through a fully-jitted partition walk:
   * with a ``mesh``, each micro-batch fans out across the mesh's
     data-parallel axes via ``shard_map`` — the walk is per-flow, so no
     collectives are needed and scaling is embarrassingly parallel;
-  * off-CPU the packet buffer is donated, so back-to-back chunks reuse
-    one device allocation instead of growing the live set;
+  * each chunk's packet buffer is dropped once its walk is dispatched,
+    so the device holds at most ``inflight + 1`` chunks of packets (the
+    buffer is not donated: no output of the walk could reuse it);
   * results land in preallocated host arrays — one device→host
     transfer per micro-batch, none per partition.
 
@@ -54,7 +55,6 @@ from typing import Iterable, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro import obs
@@ -72,17 +72,10 @@ from repro.core.inference import (
     get_backend,
     pallas_backend,
     partition_walk,
-    partition_walk_donated,
 )
 from repro.distributed.sharding import flow_batch_devices, flow_batch_spec
 from repro.kernels.compaction import COMPACT_FLOOR
 from repro.kernels.dispatch import pad_axis0, round_up
-
-
-def _should_donate(donate: bool | None) -> bool:
-    if donate is None:
-        return jax.default_backend() != "cpu"
-    return donate
 
 
 def _walk_backend(engine: Engine, impl: str | None) -> ExecutionBackend:
@@ -129,20 +122,19 @@ def _resolve_backend(engine: Engine, opt: EngineOptions, mb: int, win_pkts):
     return (backend_for_plan(plan), plan.compact, plan.compact_floor, plan)
 
 
-def _single_device_walk(n_subtrees: int, donate: bool, step: StepFn,
+def _single_device_walk(n_subtrees: int, step: StepFn,
                         compact: bool = False, floor: int = COMPACT_FLOOR):
     """(batch, dev) -> (labels, recircs, exit_partition).  No caching
     needed: partition_walk is already jitted at module level, and its
     compile cache keys on the same static (n_subtrees, step, compact,
     compact_floor) args."""
-    walk = partition_walk_donated if donate else partition_walk
-    return lambda batch, dev: walk(batch, dev, n_subtrees=n_subtrees,
-                                   with_trace=False, step=step,
-                                   compact=compact, compact_floor=floor)[:3]
+    return lambda batch, dev: partition_walk(
+        batch, dev, n_subtrees=n_subtrees, with_trace=False, step=step,
+        compact=compact, compact_floor=floor)[:3]
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_walk(mesh, n_subtrees: int, donate: bool, step: StepFn,
+def _sharded_walk(mesh, n_subtrees: int, step: StepFn,
                   compact: bool = False, floor: int = COMPACT_FLOOR):
     """shard_map'd walk: the flow axis splits over the mesh's
     data-parallel axes; the device tables replicate.  The walk carries
@@ -157,13 +149,13 @@ def _sharded_walk(mesh, n_subtrees: int, donate: bool, step: StepFn,
             compact=compact, compact_floor=floor)
         return labels, recircs, exit_p
 
-    # check_rep=False: the body is collective-free by construction, and
+    # check_vma=False: the body is collective-free by construction, and
     # pallas_call (the pallas backend's step) has no replication rule
-    sharded = shard_map(body, mesh=mesh,
-                        in_specs=(spec, PartitionSpec()),
-                        out_specs=(spec, spec, spec),
-                        check_rep=False)
-    return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+    sharded = jax.shard_map(body, mesh=mesh,
+                            in_specs=(spec, PartitionSpec()),
+                            out_specs=(spec, spec, spec),
+                            check_vma=False)
+    return jax.jit(sharded)
 
 
 def microbatches(n: int, micro_batch: int) -> Iterator[tuple[int, int]]:
@@ -180,7 +172,6 @@ def run_streaming(
     *,
     options: EngineOptions | None = None,
     micro_batch=_UNSET,
-    donate=_UNSET,
     mesh=_UNSET,
     impl=_UNSET,
     inflight=_UNSET,
@@ -211,7 +202,7 @@ def run_streaming(
     synchronous PR 1 behaviour.
     """
     opt = _legacy_options(options, {
-        "micro_batch": micro_batch, "donate": donate, "mesh": mesh,
+        "micro_batch": micro_batch, "mesh": mesh,
         "impl": impl, "inflight": inflight, "compact": compact})
     P = engine._check_windows(win_pkts)
     B = win_pkts.shape[0]
@@ -221,12 +212,10 @@ def run_streaming(
         mb = round_up(mb, flow_batch_devices(mesh))
     backend, cpt, floor, plan = _resolve_backend(engine, opt, mb, win_pkts)
     if mesh is not None:
-        walk = _sharded_walk(mesh, engine.ret.n_subtrees,
-                             _should_donate(opt.donate), backend.step, cpt,
-                             floor)
+        walk = _sharded_walk(mesh, engine.ret.n_subtrees, backend.step,
+                             cpt, floor)
     else:
-        walk = _single_device_walk(engine.ret.n_subtrees,
-                                   _should_donate(opt.donate), backend.step,
+        walk = _single_device_walk(engine.ret.n_subtrees, backend.step,
                                    cpt, floor)
 
     # int32 throughout with the walk's -1 sentinels as the fill value:
@@ -282,7 +271,6 @@ def stream_batches(
     *,
     options: EngineOptions | None = None,
     micro_batch=_UNSET,
-    donate=_UNSET,
     mesh=_UNSET,
     impl=_UNSET,
     inflight=_UNSET,
@@ -295,7 +283,7 @@ def stream_batches(
     walk is shared across all of them as long as ``(p, W)`` match.
     """
     opt = _legacy_options(options, {
-        "micro_batch": micro_batch, "donate": donate, "mesh": mesh,
+        "micro_batch": micro_batch, "mesh": mesh,
         "impl": impl, "inflight": inflight, "compact": compact})
     for batch in batches:
         yield run_streaming(engine, batch, options=opt)

@@ -269,10 +269,17 @@ def default_coefficients(backend: str) -> Coefficients:
     Each backend family gets its own weights because the terms mean
     different things per path: looped's "call" is a train of eager op
     dispatches, fused's is one jitted launch, and pallas off-TPU pays
-    the interpreter per grid step.
+    the interpreter per grid step.  A platform without a row is an
+    error: another platform's weights would route on numbers that
+    describe a different machine.
     """
     import jax
-    platform = "tpu" if jax.default_backend() == "tpu" else "cpu"
+    platform = jax.default_backend()
+    if platform not in DEFAULT_COEFFS:
+        raise ValueError(
+            f"no cost-model coefficients for platform {platform!r} "
+            f"(rows: {', '.join(sorted(DEFAULT_COEFFS))}); pin a backend "
+            "instead of impl='auto'")
     return DEFAULT_COEFFS[platform][backend]
 
 

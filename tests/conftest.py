@@ -18,9 +18,6 @@ def run_subprocess(code: str, devices: int = 8, timeout: int = 600):
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    # repro/__init__ installs jax forward-compat shims (AxisType,
-    # make_mesh axis_types, ...) that the code strings rely on
-    code = "import repro  # noqa: F401\n" + code
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=timeout, env=env)
     if out.returncode != 0:

@@ -68,6 +68,19 @@ print("ok")
     assert "ok" in run_subprocess(code, devices=8)
 
 
+def test_abstract_mesh_follows_set_mesh():
+    """``models/layers.py`` reads the ambient mesh through
+    ``get_abstract_mesh()``; on current JAX ``jax.set_mesh`` installs it
+    for the block and restores the empty mesh after."""
+    import jax
+    from repro.launch.mesh import make_host_mesh
+    assert jax.sharding.get_abstract_mesh().empty
+    with jax.set_mesh(make_host_mesh()):
+        inside = jax.sharding.get_abstract_mesh()
+        assert dict(inside.shape) == {"data": 1, "model": 1}
+    assert jax.sharding.get_abstract_mesh().empty
+
+
 # ---------------------------------------------------------------------------
 # multi-device subprocess tests
 # ---------------------------------------------------------------------------
@@ -106,7 +119,6 @@ print("ok")
 def test_compressed_psum_shard_map():
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P, AxisType
 from repro.distributed.compression import compressed_psum
 
@@ -117,8 +129,8 @@ def f(g_local):
     out, err = compressed_psum(g_local[0], "pod")
     return out[None], err[None]
 
-out, err = jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod"),
-                   out_specs=(P("pod"), P("pod"))))(g)
+out, err = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                 out_specs=(P("pod"), P("pod"))))(g)
 ref = g.mean(axis=0)
 got = np.asarray(out)[0]
 rel = np.abs(got - np.asarray(ref)).max() / np.abs(ref).max()

@@ -82,9 +82,15 @@ def test_stream_batches_generator(stream_setup):
 
 
 def test_streaming_donate_flag_explicit(stream_setup):
-    """donate=False must be honoured on any backend and stay exact."""
+    """There is no donation knob: no output of the walk can reuse the
+    packet buffer on any backend, so asking for one is an error rather
+    than a silent no-op — and the stream stays exact without it."""
     eng, wp, full, _ = stream_setup
-    res = run_streaming(eng, wp, options=EngineOptions(micro_batch=33, donate=False))
+    with pytest.raises(TypeError):
+        EngineOptions(donate=True)
+    with pytest.raises(TypeError):
+        run_streaming(eng, wp, donate=False)
+    res = run_streaming(eng, wp, options=EngineOptions(micro_batch=33))
     _assert_same(res, full)
 
 

@@ -134,6 +134,16 @@ def test_default_coefficients_route_sanely():
             assert choose_plan(_shape(B=B, S=S)).backend == "fused"
 
 
+def test_default_coefficients_reject_unknown_platform(monkeypatch):
+    """A platform with no coefficient row is an error, never a silent
+    fallback to another platform's weights."""
+    import jax
+    from repro.tuning.costmodel import default_coefficients
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(ValueError, match="gpu"):
+        default_coefficients("fused")
+
+
 def test_fit_coefficients_recovers_synthetic_weights():
     """Generate timings from known weights; the NNLS fit must recover
     them (and estimates must reproduce the synthetic timings)."""

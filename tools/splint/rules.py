@@ -284,8 +284,7 @@ def check_r004(ctx: LintContext):
 # ---------------------------------------------------------------------------
 
 _SHIM_FILES = ("src/repro/core/inference.py", "src/repro/serve/streaming.py")
-_LEGACY_KWARGS = {"impl", "compact", "micro_batch", "inflight", "donate",
-                  "mesh"}
+_LEGACY_KWARGS = {"impl", "compact", "micro_batch", "inflight", "mesh"}
 _ENGINE_ENTRY_POINTS = {"run", "run_streaming", "run_looped",
                         "stream_batches"}
 
@@ -313,7 +312,7 @@ def _r005_fix(ctx: LintContext, node: ast.Call,
 
 @rule("R005", "no-legacy-engine-kwargs",
       "Engine.run/run_streaming/run_looped/stream_batches legacy "
-      "keywords (impl=/compact=/micro_batch=/inflight=/donate=/mesh=) "
+      "keywords (impl=/compact=/micro_batch=/inflight=/mesh=) "
       "are a deprecation shim; new call sites pass "
       "options=EngineOptions(...). Autofixable with --fix.",
       applies=lambda ctx: ctx.path not in _SHIM_FILES)
