@@ -1,0 +1,8 @@
+"""Tick packing (_process_resident_fused): ``tick/pack`` span seconds
+per ingest call in the traced window, in ms."""
+from benchmarks.chip.trace_reduce import span_seconds
+
+
+def read(ctx):
+    s = ctx["trace"] and span_seconds(ctx["trace"], "tick/pack")
+    return None if s is None or not ctx["ticks"] else s * 1e3 / ctx["ticks"]
