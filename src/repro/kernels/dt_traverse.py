@@ -101,6 +101,7 @@ def dt_traverse_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb * bb, 1), jnp.int32),
         interpret=interpret,
+        name="dt_traverse_pallas",
     )(block_sid, regs, thresholds,
       jnp.swapaxes(leaf_lo, 1, 2), jnp.swapaxes(leaf_hi, 1, 2),
       leaf_action[:, None, :], leaf_valid[:, None, :])

@@ -159,6 +159,7 @@ def feature_window_pallas(
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((Bp, k), jnp.float32),
         interpret=interpret,
+        name="feature_window_pallas",
     )(jnp.moveaxis(pkts, 2, 0), slot_op, slot_field, slot_pred, slot_init)
     return out[:B]
 
@@ -240,6 +241,7 @@ def feature_update_pallas(
         out_shape=[jax.ShapeDtypeStruct((Bp, k), jnp.float32),
                    jax.ShapeDtypeStruct((Bp, k), jnp.int32)],
         interpret=interpret,
+        name="feature_update_pallas",
     )(pkt, slot_op, slot_field, slot_pred, acc, seen)
     return acc2[:B], seen2[:B]
 
@@ -327,6 +329,7 @@ def feature_update_finalize_pallas(
                    jax.ShapeDtypeStruct((Bp, k), jnp.int32),
                    jax.ShapeDtypeStruct((Bp, k), jnp.float32)],
         interpret=interpret,
+        name="feature_update_finalize_pallas",
     )(pkt, slot_op, slot_field, slot_pred, slot_init, acc, seen)
     return acc2[:B], seen2[:B], regs[:B]
 

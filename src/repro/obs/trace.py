@@ -1,11 +1,14 @@
 """Nestable wall-clock spans that line up with device traces.
 
-``span("tick/dispatch")`` times a host-side region and, when a jax
-profiler session is active, emits a ``jax.profiler.TraceAnnotation``
-so the host span shows up alongside device ops in
-TensorBoard/perfetto.  Spans nest: entering a span while another is
-open records the child under the parent, and ``span_tree()`` renders
-the accumulated hierarchy.
+``span("tick/dispatch")`` times a host-side region and opens a
+``jax.profiler.TraceAnnotation`` of the same name around it (on every
+enabled span; the annotation costs little when no profiler session
+records it), so in a profiler trace the host span lies on the same
+clock as the device ops.  Keyword arguments
+(``span("tick/ingest", tick=7)``) go to the annotation and come back
+as the trace event's stats; the name stays as given.  Spans nest:
+entering a span while another is open records the child under the
+parent, and ``span_tree()`` renders the accumulated hierarchy.
 
 The global switch is the ``SPLIDT_OBS`` environment variable (read
 once at import; flip at runtime with :func:`set_enabled`).  When
@@ -98,10 +101,11 @@ _STATE = _SpanState()
 class _Span:
     """Context manager for one timed region (enabled path)."""
 
-    __slots__ = ("name", "_t0", "_node", "_annot")
+    __slots__ = ("name", "args", "_t0", "_node", "_annot")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, args: dict):
         self.name = name
+        self.args = args
         self._t0 = 0.0
         self._node: Optional[SpanNode] = None
         self._annot = None
@@ -112,7 +116,7 @@ class _Span:
         _STATE.stack.append(self._node)
         annot = _trace_annotation()
         if annot is not None:
-            self._annot = annot(self.name)
+            self._annot = annot(self.name, **self.args)
             self._annot.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -142,30 +146,37 @@ class _NullSpan:
 
 
 _NULL = _NullSpan()
+_UNRESOLVED = object()
+_ANNOTATION = _UNRESOLVED
 
 
 def _trace_annotation():
     """``jax.profiler.TraceAnnotation`` if jax is importable, else None.
 
-    Resolved lazily so ``repro.obs`` stays importable without jax (the
-    metrics half is pure numpy) and so a missing profiler degrades to
-    plain wall-clock spans.
+    Resolved on the first enabled span and kept, so ``repro.obs`` stays
+    importable without jax (the metrics half is pure numpy) and a
+    missing profiler degrades to plain wall-clock spans.
     """
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation
-    except Exception:
-        return None
+    global _ANNOTATION
+    if _ANNOTATION is _UNRESOLVED:
+        try:
+            import jax
+            _ANNOTATION = jax.profiler.TraceAnnotation
+        except Exception:
+            _ANNOTATION = None
+    return _ANNOTATION
 
 
-def span(name: str):
+def span(name: str, **args):
     """Open a timed region.  ``with span("tick/admit"): ...``
 
-    No-op (shared null context) when observability is disabled.
+    ``args`` become the trace annotation's stats
+    (``span("tick/ingest", tick=n)``).  No-op (the shared null context,
+    no span object) when observability is disabled.
     """
     if not _ENABLED:
         return _NULL
-    return _Span(name)
+    return _Span(name, args)
 
 
 def span_tree() -> str:

@@ -231,7 +231,6 @@ class FlowTable:
         self.capacity = n_buckets * bucket_size
         self.key = np.full(self.capacity, -1, np.int64)   # -1 = free slot
         self._slot_of: dict[int, int] = {}
-        self.probe_overflows = 0    # inserts that left their home bucket
 
     @property
     def resident(self) -> int:
@@ -247,34 +246,37 @@ class FlowTable:
         return np.fromiter((get(int(k), -1) for k in keys), np.int64,
                            count=keys.size)
 
-    def _insert_at(self, key: int, b0: int) -> int:
+    def _insert_at(self, key: int, b0: int) -> tuple[int, int]:
+        """(slot or ``-1``, buckets examined) for one key."""
         for probe in range(self.n_buckets):
             b = (b0 + probe) % self.n_buckets
             base = b * self.bucket_size
             free = np.nonzero(
                 self.key[base:base + self.bucket_size] == -1)[0]
             if free.size:
-                if probe:
-                    self.probe_overflows += 1
                 slot = base + int(free[0])
                 self.key[slot] = key
                 self._slot_of[key] = slot
-                return slot
-        return -1
+                return slot, probe + 1
+        return -1, self.n_buckets
 
     def insert(self, key: int) -> int | None:
         b0 = int(_mix64(np.int64(key)) % np.uint64(self.n_buckets))
-        slot = self._insert_at(int(key), b0)
+        slot, _ = self._insert_at(int(key), b0)
         return None if slot < 0 else slot
 
-    def insert_batch(self, keys: np.ndarray) -> np.ndarray:
-        """Insert keys in order; slot per key, ``-1`` where full."""
+    def insert_batch(self, keys: np.ndarray) -> tuple[np.ndarray, int]:
+        """Insert keys in order: (slot per key, ``-1`` where full; the
+        buckets examined, one per bucket visited and ``n_buckets`` for
+        a key the full table refused)."""
         keys = np.asarray(keys, np.int64)
         homes = _mix64(keys) % np.uint64(self.n_buckets)
         out = np.empty(keys.size, np.int64)
+        probes = 0
         for i in range(keys.size):
-            out[i] = self._insert_at(int(keys[i]), int(homes[i]))
-        return out
+            out[i], n = self._insert_at(int(keys[i]), int(homes[i]))
+            probes += n
+        return out, probes
 
     def free(self, slot: int) -> None:
         key = int(self.key[slot])
@@ -319,15 +321,21 @@ class ServerStats:
 
     Since the obs PR the numbers live in the server's
     :class:`repro.obs.MetricRegistry` (``serve_*`` metrics); this
-    class keeps the historical eight-field attribute API
-    (``srv.stats.dispatches`` etc.) as properties over the registry,
-    so stats appear in Prometheus/JSONL exposition for free.
-    ``ServerStats()`` with no argument gets a private registry —
-    the pre-PR standalone behaviour.
+    class keeps the attribute API (``srv.stats.dispatches`` etc.) as
+    properties over the registry, so stats appear in Prometheus/JSONL
+    exposition for free.  ``ServerStats()`` with no argument gets a
+    private registry.
+
+    ``ENGINE_DEPENDENT`` names the fields that differ between the
+    fused and legacy tick engines by design (how many device calls
+    and device->host copies serve the same ticks); every other field
+    is the same for both.
     """
 
     FIELDS = ("packets", "flows_seen", "verdicts", "spilled", "evicted",
-              "peak_resident", "ticks", "dispatches")
+              "peak_resident", "ticks", "dispatches", "d2h_bytes",
+              "insert_probes")
+    ENGINE_DEPENDENT = ("dispatches", "d2h_bytes")
 
     def __init__(self, registry: MetricRegistry | None = None):
         self.registry = registry if registry is not None else MetricRegistry()
@@ -346,6 +354,10 @@ class ServerStats:
         "serve_ticks_total", "ingest calls served")
     dispatches = _counter_stat(
         "serve_dispatches_total", "jitted device calls issued (not syncs)")
+    d2h_bytes = _counter_stat(
+        "serve_d2h_bytes_total", "bytes copied device -> host")
+    insert_probes = _counter_stat(
+        "serve_insert_probes_total", "hash buckets examined by inserts")
 
     @property
     def peak_resident(self):
@@ -610,33 +622,43 @@ class FlowTableServer:
         for hits.  Admitted slots are re-initialised in one batch
         (``_admit_batch``); ``flows_seen`` counts once from the masks.
         """
-        uniq, first_idx, inv = np.unique(fid, return_index=True,
-                                         return_inverse=True)
-        code = self.table.lookup_batch(uniq)
-        miss = np.nonzero(code < 0)[0]
-        if miss.size:
-            keys = uniq[miss]
-            retired = np.fromiter((int(k) in self._retired for k in keys),
-                                  np.bool_, count=keys.size)
-            spilled = np.fromiter((int(k) in self._spill for k in keys),
-                                  np.bool_, count=keys.size)
-            code[miss[retired]] = -1
-            code[miss[spilled]] = -2
-            new = miss[~retired & ~spilled]
-            if new.size:
-                new = new[np.argsort(first_idx[new], kind="stable")]
-                lens = flen[first_idx[new]]
-                slots = self.table.insert_batch(uniq[new])
-                ok = slots >= 0
-                code[new] = np.where(ok, slots, -2)
-                for j in np.nonzero(~ok)[0]:   # table full: host spill
-                    self._spill[int(uniq[new[j]])] = _SpillFlow(
-                        length=max(int(lens[j]), 1))
-                self.stats.spilled += int(np.count_nonzero(~ok))
-                self.stats.flows_seen += int(new.size)
-                if ok.any():
-                    self._admit_batch(slots[ok], lens[ok])
-        return code[inv]
+        with span("tick/admit/lookup"):
+            uniq, first_idx, inv = np.unique(fid, return_index=True,
+                                             return_inverse=True)
+            code = self.table.lookup_batch(uniq)
+            miss = np.nonzero(code < 0)[0]
+        with span("tick/admit/insert"):
+            admit = None
+            if miss.size:
+                keys = uniq[miss]
+                retired = np.fromiter(
+                    (int(k) in self._retired for k in keys),
+                    np.bool_, count=keys.size)
+                spilled = np.fromiter(
+                    (int(k) in self._spill for k in keys),
+                    np.bool_, count=keys.size)
+                code[miss[retired]] = -1
+                code[miss[spilled]] = -2
+                new = miss[~retired & ~spilled]
+                if new.size:
+                    new = new[np.argsort(first_idx[new], kind="stable")]
+                    lens = flen[first_idx[new]]
+                    slots, probes = self.table.insert_batch(uniq[new])
+                    ok = slots >= 0
+                    code[new] = np.where(ok, slots, -2)
+                    for j in np.nonzero(~ok)[0]:   # table full: host spill
+                        self._spill[int(uniq[new[j]])] = _SpillFlow(
+                            length=max(int(lens[j]), 1))
+                    self.stats.spilled += int(np.count_nonzero(~ok))
+                    self.stats.flows_seen += int(new.size)
+                    self.stats.insert_probes += probes
+                    if ok.any():
+                        admit = slots[ok], lens[ok]
+            routed = code[inv]
+        if admit is not None:
+            with span("tick/admit/rows"):
+                self._admit_batch(*admit)
+        return routed
 
     def _admit_batch(self, slots: np.ndarray, lengths: np.ndarray) -> None:
         """Initialise newly admitted slots (recycled slots carry the
@@ -673,47 +695,70 @@ class FlowTableServer:
 
     # -- ingest ---------------------------------------------------------
     def ingest(self, batch) -> StreamVerdicts:
-        """Fold one tick of packet arrivals; return completed verdicts."""
-        fid = np.asarray(batch.flow_id, np.int64)
-        flen = np.asarray(batch.flow_len, np.int64)
-        pk = np.asarray(batch.pkts, np.float32)
-        arr = np.asarray(batch.arrival, np.float64)
-        n = int(fid.shape[0])
-        self.stats.packets += n
-        self.stats.ticks += 1
-        if n:
-            self._now = max(self._now, float(arr.max()))
-        out = _VerdictAccum()
+        """Fold one tick of packet arrivals; return completed verdicts.
 
-        # route every packet: resident slot, spill store, or retired-drop
-        with span("tick/admit"):
-            slot_pk = (self._route_tick(fid, flen) if n
-                       else np.empty(0, np.int64))
-        self.stats.peak_resident = max(self.stats.peak_resident,
-                                       self.resident_flows)
+        On the fused tick engine every host statement of the call lies
+        under exactly one leaf span, and the children of a span run one
+        after another (docs/OBSERVABILITY.md draws the tree):
+        ``tick/stamp`` opens twice, before admission (the batch as
+        arrays, counters, stream clock) and after it (spill rows,
+        arrival stamps).
+        """
+        with span("tick/ingest", tick=self.stats.ticks + 1):
+            with span("tick/stamp"):
+                fid = np.asarray(batch.flow_id, np.int64)
+                flen = np.asarray(batch.flow_len, np.int64)
+                pk = np.asarray(batch.pkts, np.float32)
+                arr = np.asarray(batch.arrival, np.float64)
+                n = int(fid.shape[0])
+                self.stats.packets += n
+                self.stats.ticks += 1
+                if n:
+                    self._now = max(self._now, float(arr.max()))
+                out = _VerdictAccum()
 
-        spill_rows = np.nonzero(slot_pk == -2)[0]
-        for i in spill_rows:
-            f = self._spill[int(fid[i])]
-            f.rows.append(pk[i])
-            ts = float(arr[i])
-            f.last_ts = max(f.last_ts, ts)
-            f.first_ts = min(f.first_ts, ts)
+            # route every packet: resident slot, spill store, or retired-drop
+            with span("tick/admit"):
+                slot_pk = (self._route_tick(fid, flen) if n
+                           else np.empty(0, np.int64))
 
-        res_rows = np.nonzero(slot_pk >= 0)[0]
-        if res_rows.size:
-            self._process_resident(slot_pk[res_rows], fid[res_rows],
-                                   pk[res_rows], arr[res_rows], out)
-        self._run_spilled_complete(out)
-        if self.timeout is not None and n:
-            self._evict_timeouts(float(arr.max()), out)
-        self.stats.verdicts += out.n
-        return self._finish(out)
+            with span("tick/stamp"):
+                self.stats.peak_resident = max(self.stats.peak_resident,
+                                               self.resident_flows)
+                spill_rows = np.nonzero(slot_pk == -2)[0]
+                for i in spill_rows:
+                    f = self._spill[int(fid[i])]
+                    f.rows.append(pk[i])
+                    ts = float(arr[i])
+                    f.last_ts = max(f.last_ts, ts)
+                    f.first_ts = min(f.first_ts, ts)
+                res_rows = np.nonzero(slot_pk >= 0)[0]
+                if res_rows.size:
+                    slots = slot_pk[res_rows]
+                    np.minimum.at(self._first_ts, slots, arr[res_rows])
+                    np.maximum.at(self._last_ts, slots, arr[res_rows])
+                    pkts = pk[res_rows]
+
+            if res_rows.size:
+                if self.tick_engine == "fused":
+                    self._process_resident_fused(slots, pkts, out)
+                else:
+                    self._process_resident_legacy(slots, fid[res_rows],
+                                                  pkts, out)
+            with span("tick/spill"):
+                self._run_spilled_complete(out)
+            if self.timeout is not None and n:
+                with span("tick/timeout"):
+                    self._evict_timeouts(float(arr.max()), out)
+            with span("tick/finish"):
+                self.stats.verdicts += out.n
+                return self._finish(out)
 
     def flush(self) -> StreamVerdicts:
         """End of stream: evict every resident flow with sentinels."""
         out = _VerdictAccum()
-        self._run_spilled_complete(out)
+        with span("tick/spill"):
+            self._run_spilled_complete(out)
         live = np.nonzero(self.table.key >= 0)[0]
         if live.size:
             neg = np.full(live.size, -1, np.int32)
@@ -757,6 +802,13 @@ class FlowTableServer:
         slots[:s.size] = s
         return cap, slots
 
+    def _to_host(self, arrays) -> list[np.ndarray]:
+        """The arrays as numpy, counting the bytes copied off the device
+        (the batch walk's results arrive already copied)."""
+        host = [np.asarray(a) for a in jax.device_get(arrays)]
+        self.stats.d2h_bytes += sum(a.nbytes for a in host)
+        return host
+
     def _reset_admitted(self, s: np.ndarray) -> None:
         cap, slots = self._pad_slots(s)
         self._acc, self._seen = _reset_rows(
@@ -781,14 +833,6 @@ class FlowTableServer:
         rank = np.arange(ss.size) - grp_start[grp_id]
         return order, ss, grp_id, rank
 
-    def _process_resident(self, slots, fids, pkts, arr, out) -> None:
-        np.minimum.at(self._first_ts, slots, arr)
-        np.maximum.at(self._last_ts, slots, arr)
-        if self.tick_engine == "fused":
-            self._process_resident_fused(slots, pkts, out)
-        else:
-            self._process_resident_legacy(slots, fids, pkts, out)
-
     def _process_resident_fused(self, slots, pkts, out) -> None:
         """One jitted dispatch for the whole tick, one bulk fetch.
 
@@ -798,6 +842,9 @@ class FlowTableServer:
         power-of-two ladder so jit compiles a handful of shapes.  The
         retired-flow guard, IAT window reset, fold, completion hop, and
         empty-window drain all run inside ``kernels.tick_step``.
+        ``tick/fetch/wait`` waits for the device before the copy
+        (``tick/fetch/copy``), traced or not, so a trace tells the two
+        apart.
         """
         with span("tick/pack"):
             order, ss, grp_id, rank = self._rank_decompose(slots)
@@ -808,21 +855,28 @@ class FlowTableServer:
             slots_rc[rank, grp_id] = ss
             pkt_rc[rank, grp_id] = pkts[order]
         with span("tick/dispatch"):
-            self._tstate, res = _tick.tick_step(
-                self._tstate, jnp.asarray(slots_rc), jnp.asarray(pkt_rc),
-                self.engine.dev, n_subtrees=self.S,
-                pallas=self._pallas, block_b=self._block_b)
-            self.stats.dispatches += 1
+            with span("tick/dispatch/put"):
+                slots_d = jnp.asarray(slots_rc)
+                pkt_d = jnp.asarray(pkt_rc)
+            with span("tick/dispatch/call"):
+                self._tstate, res = _tick.tick_step(
+                    self._tstate, slots_d, pkt_d, self.engine.dev,
+                    n_subtrees=self.S, pallas=self._pallas,
+                    block_b=self._block_b)
+                self.stats.dispatches += 1
         with span("tick/fetch"):
-            vm, vl, vr, ve, rec = (
-                np.asarray(a) for a in jax.device_get(res))
-        self._recircs = rec                   # host mirror (flush/timeout)
-        done = np.nonzero(vm)[0]
-        if done.size:
-            out.add_batch(self.table.key[done], vl[done], vr[done],
-                          ve[done], self._first_ts[done])
-            for slot in done:
-                self._evict(int(slot))
+            with span("tick/fetch/wait"):
+                jax.block_until_ready(res)
+            with span("tick/fetch/copy"):
+                vm, vl, vr, ve, rec = self._to_host(res)
+        with span("tick/evict"):
+            self._recircs = rec               # host mirror (flush/timeout)
+            done = np.nonzero(vm)[0]
+            if done.size:
+                out.add_batch(self.table.key[done], vl[done], vr[done],
+                              ve[done], self._first_ts[done])
+                for slot in done:
+                    self._evict(int(slot))
 
     def _process_resident_legacy(self, slots, fids, pkts, out) -> None:
         order, _, _, rank = self._rank_decompose(slots)
@@ -884,8 +938,7 @@ class FlowTableServer:
             self._acc, self._seen = res[0], res[1]
             with span("tick/fetch"):
                 labels, done, sid2, rec2, exit_p = (
-                    np.asarray(a)[:s.size]
-                    for a in jax.device_get(res[2:]))
+                    a[:s.size] for a in self._to_host(res[2:]))
             done = done.astype(bool)
             # exits emit verdicts; flows falling off the last partition
             # emit -1 sentinels; the rest advance to the next window
@@ -933,21 +986,19 @@ class FlowTableServer:
                 win = rows[lo:hi].copy()
                 win[0, PKT_IAT] = 0.0
                 wp[idx, w, :hi - lo] = win
-        with span("tick/spill"):
-            res = self.engine.run(wp, with_trace=False,
-                                  options=self._spill_options)
-            # the batch walk is a jitted device call like any tick step;
-            # both tick engines share this path, so counting it keeps
-            # fused/legacy dispatch counts comparable (it was silently
-            # uncounted before, understating spill-heavy workloads)
-            self.stats.dispatches += 1
+        res = self.engine.run(wp, with_trace=False,
+                              options=self._spill_options)
+        # the batch walk is a jitted device call like any tick step;
+        # both tick engines share this path, so counting it keeps
+        # fused/legacy dispatch counts comparable
+        self.stats.dispatches += 1
+        labels, recircs, exit_p = self._to_host(
+            (res.labels, res.recircs, res.exit_partition))
         n = len(done)
         first = np.asarray([self._spill[k].first_ts for k in done],
                            np.float64)
-        out.add_batch(np.asarray(done, np.int64),
-                      np.asarray(res.labels)[:n],
-                      np.asarray(res.recircs)[:n],
-                      np.asarray(res.exit_partition)[:n], first)
+        out.add_batch(np.asarray(done, np.int64), labels[:n], recircs[:n],
+                      exit_p[:n], first)
         for key in done:
             del self._spill[key]
             self._retired.add(key)

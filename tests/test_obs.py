@@ -171,6 +171,47 @@ def test_null_span_is_shared_singleton():
         obs.set_enabled(prev)
 
 
+def test_null_span_with_trace_args_is_shared():
+    """Trace arguments do not open a way round the no-op: a disabled
+    ``span(name, tick=n)`` is the same shared object."""
+    prev = obs.set_enabled(False)
+    try:
+        assert obs.span("tick/ingest", tick=7) is obs.span("a")
+        with obs.span("tick/ingest", tick=8):
+            pass
+        assert obs.span_tree() == "(no spans recorded)"
+    finally:
+        obs.set_enabled(prev)
+
+
+def test_enabled_span_passes_trace_args(monkeypatch):
+    """An enabled span hands its keyword arguments to the profiler
+    annotation under the span's own name."""
+    from repro.obs import trace
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **args):
+            seen.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace, "_ANNOTATION", Annotation)
+    prev = obs.set_enabled(True)
+    try:
+        with obs.span("tick/ingest", tick=3):
+            with obs.span("tick/stamp"):
+                pass
+    finally:
+        obs.set_enabled(prev)
+        obs.reset_spans()
+    assert seen == [("tick/ingest", {"tick": 3}), ("tick/stamp", {})]
+
+
 # ---------------------------------------------------------------------------
 # reporter
 # ---------------------------------------------------------------------------

@@ -198,11 +198,12 @@ def test_tick_engines_bit_identical(tick_setup, impl):
     np.testing.assert_array_equal(a.recircs[oa], b.recircs[ob])
     np.testing.assert_array_equal(a.exit_partition[oa],
                                   b.exit_partition[ob])
-    # same admission story: EVERY stats field except the dispatch count
-    # (the engines' whole difference) agrees
+    # same admission story: EVERY stats field except the engine-dependent
+    # ones (device calls and copies, the engines' whole difference)
+    # agrees, the hash probes included
     from repro.serve import ServerStats
     for f in ServerStats.FIELDS:
-        if f == "dispatches":
+        if f in ServerStats.ENGINE_DEPENDENT:
             continue
         assert getattr(sa, f) == getattr(sb, f), f
     assert sa.dispatches < sb.dispatches  # the whole point
@@ -231,7 +232,45 @@ def test_tick_engines_stats_agree_under_spill_and_timeout(tick_setup):
     np.testing.assert_array_equal(a.flow_id[oa], b.flow_id[ob])
     np.testing.assert_array_equal(a.labels[oa], b.labels[ob])
     for f in ServerStats.FIELDS:
-        if f == "dispatches":
+        if f in ServerStats.ENGINE_DEPENDENT:
             continue
         assert getattr(sa, f) == getattr(sb, f), f
     assert sa.dispatches < sb.dispatches
+    # a placed key examines one bucket or more, a refused key all 2
+    assert sa.insert_probes >= sa.flows_seen - sa.spilled + 2 * sa.spilled
+
+
+# ---------------------------------------------------------------------------
+# transfer and probe counters
+# ---------------------------------------------------------------------------
+def test_fused_tick_copies_five_verdict_rows_per_slot(tick_setup):
+    """Each fused tick that serves resident packets copies its five
+    (N,) int32 verdict buffers to the host, and nothing else does while
+    no flow spills."""
+    eng, tr, stream = tick_setup
+    srv = FlowTableServer(eng, n_buckets=64, bucket_size=8,
+                          tick_engine="fused")
+    N = srv.table.capacity
+    for b in stream.ticks(97):
+        before = srv.stats.d2h_bytes
+        srv.ingest(b)
+        assert srv.stats.d2h_bytes - before == 5 * 4 * N
+    assert srv.stats.spilled == 0
+
+
+def test_insert_probes_agree_across_engines(tick_setup):
+    """The hash probes depend on admission alone: the same stream gives
+    the same count in both engines, at least one bucket per placed flow
+    and more once buckets overflow into their neighbours."""
+    eng, tr, stream = tick_setup
+    stats = {}
+    for te in ("fused", "legacy"):
+        srv = FlowTableServer(eng, n_buckets=8, bucket_size=4,
+                              tick_engine=te)
+        for b in stream.ticks(97):
+            srv.ingest(b)
+        stats[te] = srv.stats
+    sa, sb = stats["fused"], stats["legacy"]
+    assert sa.insert_probes == sb.insert_probes
+    assert sa.insert_probes > sa.flows_seen - sa.spilled
+    assert sa.d2h_bytes != sb.d2h_bytes     # engine-dependent, by design
