@@ -14,8 +14,9 @@ users, heavy traffic" direction:
     with data-dependent routing).  When every probe fails the flow
     falls back to a host-side spill store instead of being dropped.
     Admission is vectorized: one NumPy group-by over the tick's flow
-    ids, one ``lookup_batch``/``insert_batch`` over the tick's unique
-    flows — no per-packet python loop;
+    ids, one ``lookup_batch`` (a bucket probe of the slot-key array)
+    and one ``insert_batch`` over the tick's unique flows — no
+    per-packet python loop;
   * the **fused tick engine** (``kernels.tick_step``, the default via
     ``tick_engine="auto"``) holds ALL per-flow serving state on device
     — window registers and the walk metadata (``sid``, partition,
@@ -216,11 +217,19 @@ class FlowTable:
     subsequent buckets (wrapping) on overflow — the data-plane analogue
     is a multi-way register hash table.  ``insert`` returns ``None``
     only when the WHOLE table is full; the server then spills to the
-    host instead of dropping the flow.  The batch forms
-    (:meth:`lookup_batch` / :meth:`insert_batch`) serve one tick's
-    UNIQUE flows in a single call — home buckets are hashed vectorized;
-    probing stays sequential because each insert's placement depends on
-    the previous one's occupancy.
+    host instead of dropping the flow.
+
+    The arrays are the whole index: ``key`` (slot -> key, ``-1`` free;
+    keys are non-negative), ``_home`` (slot -> its key's home bucket)
+    and ``_over`` (bucket -> resident keys whose insertion probed past
+    it).  A lookup walks from the home bucket and stops at the key or
+    at a bucket with ``_over == 0``: a free slot proves nothing, since
+    the key may have overflowed before that slot was freed.  The batch
+    forms serve one tick's UNIQUE flows in a single call —
+    :meth:`lookup_batch` probes every key's bucket row at once, one
+    round per bucket step; :meth:`insert_batch` stays sequential
+    because each insert's placement depends on the previous one's
+    occupancy.
     """
 
     def __init__(self, n_buckets: int, bucket_size: int):
@@ -230,39 +239,65 @@ class FlowTable:
         self.bucket_size = bucket_size
         self.capacity = n_buckets * bucket_size
         self.key = np.full(self.capacity, -1, np.int64)   # -1 = free slot
-        self._slot_of: dict[int, int] = {}
+        self._home = np.zeros(self.capacity, np.int32)
+        self._over = np.zeros(n_buckets, np.int32)
+        self.resident = 0
 
-    @property
-    def resident(self) -> int:
-        return len(self._slot_of)
+    def _homes(self, keys: np.ndarray) -> np.ndarray:
+        return (_mix64(keys) % np.uint64(self.n_buckets)).astype(np.int64)
 
     def lookup(self, key: int) -> int | None:
-        return self._slot_of.get(key)
+        slots, _ = self.lookup_batch(np.asarray([key], np.int64))
+        return None if slots[0] < 0 else int(slots[0])
 
-    def lookup_batch(self, keys: np.ndarray) -> np.ndarray:
-        """Slot per key, ``-1`` where absent (one probe per key)."""
+    def lookup_batch(self, keys: np.ndarray) -> tuple[np.ndarray, int]:
+        """(slot per key, ``-1`` where absent; the buckets examined).
+
+        Each round compares every unresolved key with its current
+        bucket's row; a key that misses moves to the next bucket only
+        if that row was overflowed by some resident key, so absent keys
+        mostly stop at home and no key examines more than
+        ``n_buckets`` buckets.
+        """
         keys = np.asarray(keys, np.int64)
-        get = self._slot_of.get
-        return np.fromiter((get(int(k), -1) for k in keys), np.int64,
-                           count=keys.size)
+        out = np.full(keys.size, -1, np.int64)
+        rows = self.key.reshape(self.n_buckets, self.bucket_size)
+        idx = np.arange(keys.size)
+        b = self._homes(keys)
+        probes = 0
+        for _ in range(self.n_buckets):
+            if not idx.size:
+                break
+            probes += idx.size
+            # keys are unique in the table: at most one match per row
+            hit_row, col = np.divmod(np.flatnonzero(
+                np.take(rows, b, axis=0) == keys[:, None]),
+                self.bucket_size)
+            out[idx[hit_row]] = b[hit_row] * self.bucket_size + col
+            go = np.take(self._over, b) > 0
+            go[hit_row] = False
+            idx, keys, b = idx[go], keys[go], (b[go] + 1) % self.n_buckets
+        return out, probes
 
     def _insert_at(self, key: int, b0: int) -> tuple[int, int]:
-        """(slot or ``-1``, buckets examined) for one key."""
+        """(slot or ``-1``, buckets examined) for one key; each full
+        bucket it passes counts it in ``_over``."""
         for probe in range(self.n_buckets):
             b = (b0 + probe) % self.n_buckets
             base = b * self.bucket_size
-            free = np.nonzero(
-                self.key[base:base + self.bucket_size] == -1)[0]
-            if free.size:
-                slot = base + int(free[0])
+            row = self.key[base:base + self.bucket_size].tolist()
+            if -1 in row:
+                slot = base + row.index(-1)
                 self.key[slot] = key
-                self._slot_of[key] = slot
+                self._home[slot] = b0
+                self.resident += 1
                 return slot, probe + 1
+            self._over[b] += 1
+        self._over -= 1                 # refused: it passed every bucket
         return -1, self.n_buckets
 
     def insert(self, key: int) -> int | None:
-        b0 = int(_mix64(np.int64(key)) % np.uint64(self.n_buckets))
-        slot, _ = self._insert_at(int(key), b0)
+        slot, _ = self._insert_at(int(key), int(self._homes(key)))
         return None if slot < 0 else slot
 
     def insert_batch(self, keys: np.ndarray) -> tuple[np.ndarray, int]:
@@ -270,18 +305,22 @@ class FlowTable:
         buckets examined, one per bucket visited and ``n_buckets`` for
         a key the full table refused)."""
         keys = np.asarray(keys, np.int64)
-        homes = _mix64(keys) % np.uint64(self.n_buckets)
         out = np.empty(keys.size, np.int64)
         probes = 0
-        for i in range(keys.size):
-            out[i], n = self._insert_at(int(keys[i]), int(homes[i]))
+        for i, (k, b0) in enumerate(zip(keys.tolist(),
+                                        self._homes(keys).tolist())):
+            out[i], n = self._insert_at(k, b0)
             probes += n
         return out, probes
 
     def free(self, slot: int) -> None:
-        key = int(self.key[slot])
-        del self._slot_of[key]
+        if self.key.item(slot) < 0:
+            raise KeyError(f"slot {slot} is free")
+        b0 = self._home.item(slot)
+        for p in range((slot // self.bucket_size - b0) % self.n_buckets):
+            self._over[(b0 + p) % self.n_buckets] -= 1
         self.key[slot] = -1
+        self.resident -= 1
 
 
 @dataclasses.dataclass
@@ -334,7 +373,7 @@ class ServerStats:
 
     FIELDS = ("packets", "flows_seen", "verdicts", "spilled", "evicted",
               "peak_resident", "ticks", "dispatches", "d2h_bytes",
-              "insert_probes")
+              "insert_probes", "lookup_probes", "lookup_keys")
     ENGINE_DEPENDENT = ("dispatches", "d2h_bytes")
 
     def __init__(self, registry: MetricRegistry | None = None):
@@ -358,6 +397,10 @@ class ServerStats:
         "serve_d2h_bytes_total", "bytes copied device -> host")
     insert_probes = _counter_stat(
         "serve_insert_probes_total", "hash buckets examined by inserts")
+    lookup_probes = _counter_stat(
+        "serve_lookup_probes_total", "hash buckets examined by lookups")
+    lookup_keys = _counter_stat(
+        "serve_lookup_keys_total", "flow keys looked up in the table")
 
     @property
     def peak_resident(self):
@@ -625,7 +668,9 @@ class FlowTableServer:
         with span("tick/admit/lookup"):
             uniq, first_idx, inv = np.unique(fid, return_index=True,
                                              return_inverse=True)
-            code = self.table.lookup_batch(uniq)
+            code, probes = self.table.lookup_batch(uniq)
+            self.stats.lookup_keys += int(uniq.size)
+            self.stats.lookup_probes += probes
             miss = np.nonzero(code < 0)[0]
         with span("tick/admit/insert"):
             admit = None

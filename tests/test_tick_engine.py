@@ -258,19 +258,42 @@ def test_fused_tick_copies_five_verdict_rows_per_slot(tick_setup):
     assert srv.stats.spilled == 0
 
 
-def test_insert_probes_agree_across_engines(tick_setup):
-    """The hash probes depend on admission alone: the same stream gives
-    the same count in both engines, at least one bucket per placed flow
-    and more once buckets overflow into their neighbours."""
+@pytest.fixture(scope="module")
+def probe_runs(tick_setup):
+    """The same stream through an 8 x 4 table on each tick engine:
+    (stats per engine, the unique flow ids summed over its ticks)."""
     eng, tr, stream = tick_setup
+    ticks = list(stream.ticks(97))
     stats = {}
     for te in ("fused", "legacy"):
         srv = FlowTableServer(eng, n_buckets=8, bucket_size=4,
                               tick_engine=te)
-        for b in stream.ticks(97):
+        for b in ticks:
             srv.ingest(b)
         stats[te] = srv.stats
+    n_keys = sum(np.unique(np.asarray(b.flow_id)).size for b in ticks)
+    return stats, n_keys
+
+
+def test_insert_probes_agree_across_engines(probe_runs):
+    """The hash probes depend on admission alone: the same stream gives
+    the same count in both engines, at least one bucket per placed flow
+    and more once buckets overflow into their neighbours."""
+    stats, _ = probe_runs
     sa, sb = stats["fused"], stats["legacy"]
     assert sa.insert_probes == sb.insert_probes
     assert sa.insert_probes > sa.flows_seen - sa.spilled
     assert sa.d2h_bytes != sb.d2h_bytes     # engine-dependent, by design
+
+
+def test_lookup_probes_agree_across_engines(probe_runs):
+    """Each tick looks its unique flow ids up once; the buckets those
+    lookups examine depend on admission alone, so both engines count
+    the same, at least one bucket per key and more once keys have
+    overflowed their home buckets."""
+    stats, n_keys = probe_runs
+    sa, sb = stats["fused"], stats["legacy"]
+    assert sa.lookup_keys == sb.lookup_keys == n_keys
+    assert sa.lookup_probes == sb.lookup_probes > sa.lookup_keys
+    assert sa.registry.counter("serve_lookup_probes_total").value == \
+        sa.lookup_probes
