@@ -234,7 +234,8 @@ def pallas_programs(setup: Setup, sizes: Sizes) -> dict:
     # a column per flow in the tick
     ranks, cols = 16, sizes.tick // 2
     lowered["FlowTableServer[pallas] tick"] = tick_step.tick_step.lower(
-        state, jax.ShapeDtypeStruct((ranks, cols), np.int32),
+        state, jax.ShapeDtypeStruct((cols,), np.int32),
+        jax.ShapeDtypeStruct((cols,), np.int32),
         jax.ShapeDtypeStruct((ranks, cols, PKT_NFIELDS), f32), eng.dev,
         n_subtrees=S, pallas=True, block_b=BLOCK_B)
     return {name: low.compile().as_text() for name, low in lowered.items()}

@@ -16,28 +16,34 @@ per-tick pipeline into ONE jitted call:
   * **admission** (:func:`admit_rows`) re-initialises newly admitted
     slots in one scatter, computing ``flows.windows.window_bounds`` with
     in-jit int32 math (bit-for-bit the host formula);
-  * **the tick step** (:func:`tick_step`) runs the rank loop as a
-    ``lax.scan`` over the tick's rank-major ``(R, C)`` slot/packet
-    arrays.  Each rank folds one packet per slot (the incremental
-    update of ``kernels.ref.feature_update_ref`` or the fused Pallas
-    fold+finalize kernel), then hops every slot whose window completed:
-    finalize → subtree traverse → the walk's own
-    ``core.inference._hop_update`` bookkeeping.  Empty trailing windows
-    (flows shorter than P packets) drain inside an in-jit bounded
-    ``lax.while_loop`` — the partition index strictly advances every
-    round, so ``P`` is a static trip bound;
-  * **verdicts** accumulate into per-slot device buffers; the server
-    issues one bulk ``device_get`` per tick and frees the finished
-    slots host-side.
+  * **the tick step** (:func:`tick_step`) works on the tick's
+    **working set**: its C columns, one per distinct slot of the tick.
+    It gathers every per-slot field of those rows once, runs the rank
+    loop as a ``lax.scan`` over the rank-major ``(R, C)`` packets on
+    that ``(C, ...)`` carry (the table is never in the carry), and
+    writes the rows back with one scatter per field at the end, so its
+    indexed table traffic is one gather and one scatter of C rows
+    whatever R is.  Each rank folds one packet per column (the
+    incremental update of ``kernels.ref.feature_update_ref`` or the
+    fused Pallas fold+finalize kernel), then hops every column whose
+    window completed: finalize → subtree traverse → the walk's own
+    ``core.inference._hop_update`` bookkeeping.  Empty trailing
+    windows (flows shorter than P packets) drain inside an in-jit
+    bounded ``lax.while_loop`` — the partition index strictly advances
+    every round, so ``P`` is a static trip bound;
+  * **verdicts** accumulate per column, ``(C,)`` each; the server
+    issues one bulk ``device_get`` per tick (5 x C int32, not a
+    table-sized buffer), maps columns to slots through ``cols`` and
+    frees the finished slots host-side.
 
 Parity (docs/PARITY.md §5): every per-row computation here is the same
-row-wise kernel math the legacy per-rank path dispatched — gathers and
-masks route rows, they never change values — so fused-tick verdicts are
-bit-identical to the host-looped path and to ``Engine.run`` on rebuilt
-windows.  Masked rows (padding, retired flows, already-hopped slots)
-are routed to the dummy row with invalidated packets; every dummy
-duplicate computes identical values, so the scatters stay
-deterministic.
+row-wise kernel math the legacy per-rank path dispatched — gathers,
+masks and the column layout route rows, they never change values — so
+fused-tick verdicts are bit-identical to the host-looped path and to
+``Engine.run`` on rebuilt windows.  A column whose rank is empty, whose
+flow already retired, or whose window did not complete keeps its row
+(``where``); padding columns read the dummy row and write it back
+unchanged, so the write-back's duplicate indices agree.
 """
 from __future__ import annotations
 
@@ -58,10 +64,11 @@ from repro.kernels.feature_window import feature_update_finalize_pallas
 class TickState(NamedTuple):
     """Device-resident per-slot serving state (``N + 1`` rows).
 
-    The last row is the dummy row: padded rank entries, retired flows,
-    and non-hopping slots are all routed there so every device op keeps
-    a static shape.  ``bounds`` caches each flow's per-partition window
-    ``[lo, hi)`` so hops never need the host.
+    The last row is the dummy row: padded admission rows and the tick's
+    padding columns address it so every device op keeps a static shape.
+    The tick's working set is a ``TickState`` of C rows.  ``bounds``
+    caches each flow's per-partition window ``[lo, hi)`` so hops never
+    need the host.
     """
     acc: jnp.ndarray        # (N+1, k) f32 running window registers
     seen: jnp.ndarray       # (N+1, k) int32 "matched yet" bits
@@ -136,152 +143,151 @@ def _traverse(regs, sid_rows, dev, *, pallas: bool, block_b: int):
         dev.leaf_valid[sid_rows] > 0)
 
 
-def _hop_round(st: TickState, vm, vl, vr, ve, h, regs, complete, dev, *,
+def _hop_round(ws: TickState, vm, vl, vr, ve, regs, complete, dev, *,
                n_subtrees: int, pallas: bool, block_b: int):
-    """One hop for the slots in ``h`` whose ``complete`` bit is set.
+    """One hop for the columns whose ``complete`` bit is set.
 
-    ``h`` (C,) routes non-completing rows to the dummy row; ``regs``
-    (C, k) are the finalized registers for the completing rows (masked
-    rows may carry anything — traversal output for them is discarded by
-    the ``complete`` masks).  Runs traverse + ``_hop_update``, scatters
-    verdicts for exiting / fell-off-the-last-partition flows into the
-    per-slot buffers, advances the survivors' partition/window/SID, and
-    returns the ``complete`` mask for the next drain round (flows whose
-    new window is empty).
+    ``ws`` is the tick's working set (one row per column); ``regs``
+    (C, k) are the finalized registers of the completing columns (the
+    rest may carry anything — their traversal output is discarded by
+    the ``complete`` masks).  Runs traverse + ``_hop_update``, records
+    verdicts for exiting / fell-off-the-last-partition flows in the
+    per-column buffers, advances the survivors' partition/window/SID,
+    and returns the ``complete`` mask for the next drain round (flows
+    whose new window is empty).
     """
-    P = st.bounds.shape[1]
-    dummy = st.sid.shape[0] - 1
-    sid_rows = st.sid[h]
-    p_rows = st.part[h]
-    rec_rows = st.recircs[h]
-    action = _traverse(regs, sid_rows, dev, pallas=pallas, block_b=block_b)
-    carry = (sid_rows, ~complete,
-             jnp.full(sid_rows.shape, -1, jnp.int32), rec_rows,
-             jnp.full(sid_rows.shape, -1, jnp.int32))
+    P = ws.bounds.shape[1]
+    action = _traverse(regs, ws.sid, dev, pallas=pallas, block_b=block_b)
+    carry = (ws.sid, ~complete, jnp.full(ws.sid.shape, -1, jnp.int32),
+             ws.recircs, jnp.full(ws.sid.shape, -1, jnp.int32))
     sid2, done2, labels, rec2, exit_p = _hop_update(
-        carry, p_rows, action, n_subtrees)
+        carry, ws.part, action, n_subtrees)
     exited = complete & done2
-    fell = complete & ~done2 & (p_rows == P - 1)
-    adv = complete & ~done2 & (p_rows < P - 1)
+    fell = complete & ~done2 & (ws.part == P - 1)
+    adv = complete & ~done2 & (ws.part < P - 1)
     newdone = exited | fell
 
-    # verdict buffers: one row per slot; a slot can finish at most once
-    # per tick (admission precedes folding, so no within-tick reuse)
-    vslot = jnp.where(newdone, h, dummy)
-    vm = vm.at[vslot].set(1)
-    vl = vl.at[vslot].set(labels)        # -1 unless the flow exited
-    vr = vr.at[vslot].set(rec2)
-    ve = ve.at[vslot].set(exit_p)        # -1 unless the flow exited
-    retired = st.retired.at[vslot].set(1)
+    # verdicts: a column finishes at most once per tick (admission
+    # precedes folding, so no within-tick reuse)
+    vm = jnp.where(newdone, 1, vm)
+    vl = jnp.where(newdone, labels, vl)     # -1 unless the flow exited
+    vr = jnp.where(newdone, rec2, vr)
+    ve = jnp.where(newdone, exit_p, ve)     # -1 unless the flow exited
 
     # survivors advance to the next partition's window; finished rows
     # keep their metadata (the host frees their slots after the fetch)
-    new_part = jnp.where(adv, p_rows + 1, p_rows)
-    nb = st.bounds[h, jnp.minimum(new_part, P - 1)]          # (C, 2)
-    new_lo = jnp.where(adv, nb[:, 0], st.win_lo[h])
-    new_hi = jnp.where(adv, nb[:, 1], st.win_hi[h])
+    new_part = jnp.where(adv, ws.part + 1, ws.part)
+    nb = jnp.take_along_axis(
+        ws.bounds, jnp.minimum(new_part, P - 1)[:, None, None],
+        axis=1)[:, 0]                                        # (C, 2)
     a0, s0 = _ref.feature_state_init(dev.slot_op[sid2])
-    st = TickState(
-        acc=st.acc.at[h].set(a0),
-        seen=st.seen.at[h].set(s0),
-        sid=st.sid.at[h].set(sid2),
-        part=st.part.at[h].set(new_part),
-        win_lo=st.win_lo.at[h].set(new_lo),
-        win_hi=st.win_hi.at[h].set(new_hi),
-        pkts_seen=st.pkts_seen,
-        recircs=st.recircs.at[h].set(rec2),
-        retired=retired,
-        bounds=st.bounds,
+    c2 = complete[:, None]
+    ws = ws._replace(
+        acc=jnp.where(c2, a0, ws.acc),
+        seen=jnp.where(c2, s0, ws.seen),
+        sid=jnp.where(complete, sid2, ws.sid),
+        part=new_part,
+        win_lo=jnp.where(adv, nb[:, 0], ws.win_lo),
+        win_hi=jnp.where(adv, nb[:, 1], ws.win_hi),
+        recircs=jnp.where(complete, rec2, ws.recircs),
+        retired=jnp.where(newdone, 1, ws.retired),
     )
-    return st, vm, vl, vr, ve, adv & (new_lo == new_hi)
+    return ws, vm, vl, vr, ve, adv & (ws.win_lo == ws.win_hi)
+
+
+#: fields the tick writes back; ``bounds`` is read-only in the tick
+_WRITTEN = tuple(f for f in TickState._fields if f != "bounds")
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_subtrees", "pallas", "block_b"))
-def tick_step(state: TickState, slots_rc: jnp.ndarray,
+def tick_step(state: TickState, cols: jnp.ndarray, n_pkts: jnp.ndarray,
               pkt_rc: jnp.ndarray, dev: ops.DeviceTables, *,
               n_subtrees: int, pallas: bool, block_b: int):
     """One ingest tick: fold every rank, hop every completed window.
 
-    ``slots_rc`` (R, C) int32 rank-major slot indices (dummy-padded;
-    within a rank each real slot appears at most once) and ``pkt_rc``
-    (R, C, F) the matching packets.  Rank order is per-flow arrival
-    order — the reduction order the parity contract pins.  Returns the
-    new state plus ``(verdict_mask, labels, recircs, exit_partition,
-    recircs_snapshot)``, each ``(N,)``, for ONE bulk ``device_get``:
-    rows with ``verdict_mask == 1`` finished this tick (exit or
-    fell-off sentinels), ``recircs_snapshot`` mirrors the live
-    recirculation counts for host-side flush/timeout sentinels.
+    ``cols`` (C,) int32 holds the tick's distinct slots, one per column
+    (padding columns address the dummy row), ``n_pkts`` (C,) int32 the
+    column's packets in the tick (0 for padding) and ``pkt_rc``
+    (R, C, F) those packets rank-major: rank r holds the r-th packet of
+    each column's flow, the per-flow arrival order the parity contract
+    pins.  The tick gathers its columns' rows once, scans the ranks on
+    that ``(C, ...)`` working set, and writes the rows back in one
+    scatter per field.  Returns the new state plus ``(verdict_mask,
+    labels, recircs, exit_partition, recircs_snapshot)``, each ``(C,)``
+    and indexed like ``cols``, for ONE bulk ``device_get``: columns
+    with ``verdict_mask == 1`` finished this tick (exit or fell-off
+    sentinels), ``recircs_snapshot`` is the columns' live recirculation
+    counts for host-side flush/timeout sentinels.
     """
-    N1 = state.sid.shape[0]
     P = state.bounds.shape[1]
-    dummy = N1 - 1
-    v0 = (jnp.zeros(N1, jnp.int32), jnp.full(N1, -1, jnp.int32),
-          jnp.zeros(N1, jnp.int32), jnp.full(N1, -1, jnp.int32))
+    C = cols.shape[0]
+    ws = TickState(*(a[cols] for a in state))
+    v0 = (jnp.zeros(C, jnp.int32), jnp.full(C, -1, jnp.int32),
+          jnp.zeros(C, jnp.int32), jnp.full(C, -1, jnp.int32))
 
     def rank_body(carry, xs):
-        st, vm, vl, vr, ve = carry
-        slots, pkt = xs
+        ws, vm, vl, vr, ve = carry
+        r, pkt = xs
         # a flow that finished earlier this tick must not fold its late
         # packets (malformed flow_len) into the slot's state: the
         # retired bit is the device form of the host's key check
-        live = (slots != dummy) & (st.retired[slots] == 0)
-        s = jnp.where(live, slots, dummy)
+        live = (r < n_pkts) & (ws.retired == 0)
         pkt = jnp.where(live[:, None], pkt, 0.0)
         # window boundary clears the dependency chain (first-packet
         # IAT = 0), matching flows.windows.window_packets
-        first = st.pkts_seen[s] == st.win_lo[s]
+        first = ws.pkts_seen == ws.win_lo
         pkt = pkt.at[:, PKT_IAT].set(jnp.where(first, 0.0, pkt[:, PKT_IAT]))
-        sid_rows = st.sid[s]
-        op = dev.slot_op[sid_rows]
-        fld = dev.slot_field[sid_rows]
-        prd = dev.slot_pred[sid_rows]
-        init = dev.slot_init[sid_rows]
+        op = dev.slot_op[ws.sid]
+        fld = dev.slot_field[ws.sid]
+        prd = dev.slot_pred[ws.sid]
+        init = dev.slot_init[ws.sid]
         if pallas:
             acc2, seen2, regs = feature_update_finalize_pallas(
-                pkt, op, fld, prd, init, st.acc[s], st.seen[s],
+                pkt, op, fld, prd, init, ws.acc, ws.seen,
                 interpret=not ops._on_tpu(), block_b=block_b)
         else:
             acc2, seen2 = _ref.feature_update_ref(
-                pkt, op, fld, prd, st.acc[s], st.seen[s])
+                pkt, op, fld, prd, ws.acc, ws.seen)
             regs = _ref.feature_finalize_ref(acc2, seen2, op, init)
-        pkts_seen = st.pkts_seen.at[s].add(live.astype(jnp.int32))
-        st = st._replace(acc=st.acc.at[s].set(acc2),
-                         seen=st.seen.at[s].set(seen2),
+        pkts_seen = ws.pkts_seen + live.astype(jnp.int32)
+        ws = ws._replace(acc=jnp.where(live[:, None], acc2, ws.acc),
+                         seen=jnp.where(live[:, None], seen2, ws.seen),
                          pkts_seen=pkts_seen)
-        complete = live & (pkts_seen[s] == st.win_hi[s])
+        complete = live & (pkts_seen == ws.win_hi)
 
         # the window-completing hop rides the SAME dispatch as the fold
         # (regs already finalized above); drain rounds only ever see
         # empty windows, whose registers finalize from blank state
-        h = jnp.where(complete, s, dummy)
-        st, vm, vl, vr, ve, nxt = _hop_round(
-            st, vm, vl, vr, ve, h, regs, complete, dev,
+        ws, vm, vl, vr, ve, nxt = _hop_round(
+            ws, vm, vl, vr, ve, regs, complete, dev,
             n_subtrees=n_subtrees, pallas=pallas, block_b=block_b)
 
         def drain_cond(c):
             return jnp.any(c[5]) & (c[6] < P)
 
         def drain_body(c):
-            st, vm, vl, vr, ve, comp, trip = c
-            hh = jnp.where(comp, s, dummy)
-            sid_h = st.sid[hh]
+            ws, vm, vl, vr, ve, comp, trip = c
             regs = _ref.feature_finalize_ref(
-                st.acc[hh], st.seen[hh], dev.slot_op[sid_h],
-                dev.slot_init[sid_h])
-            st, vm, vl, vr, ve, comp = _hop_round(
-                st, vm, vl, vr, ve, hh, regs, comp, dev,
+                ws.acc, ws.seen, dev.slot_op[ws.sid], dev.slot_init[ws.sid])
+            ws, vm, vl, vr, ve, comp = _hop_round(
+                ws, vm, vl, vr, ve, regs, comp, dev,
                 n_subtrees=n_subtrees, pallas=pallas, block_b=block_b)
-            return st, vm, vl, vr, ve, comp, trip + 1
+            return ws, vm, vl, vr, ve, comp, trip + 1
 
         # bounded: each round advances every completing flow's
         # partition, so at most P-1 iterations run (trip is a backstop)
-        st, vm, vl, vr, ve, _, _ = jax.lax.while_loop(
+        ws, vm, vl, vr, ve, _, _ = jax.lax.while_loop(
             drain_cond, drain_body,
-            (st, vm, vl, vr, ve, nxt, jnp.int32(0)))
-        return (st, vm, vl, vr, ve), None
+            (ws, vm, vl, vr, ve, nxt, jnp.int32(0)))
+        return (ws, vm, vl, vr, ve), None
 
-    (state, vm, vl, vr, ve), _ = jax.lax.scan(
-        rank_body, (state,) + v0, (slots_rc, pkt_rc))
-    N = N1 - 1
-    return state, (vm[:N], vl[:N], vr[:N], ve[:N], state.recircs[:N])
+    R = pkt_rc.shape[0]
+    (ws, vm, vl, vr, ve), _ = jax.lax.scan(
+        rank_body, (ws,) + v0, (jnp.arange(R, dtype=jnp.int32), pkt_rc))
+    # padding columns all address the dummy row; none of them is ever
+    # live, so each writes back the row it gathered and the duplicate
+    # writes agree
+    state = state._replace(**{f: getattr(state, f).at[cols].set(
+        getattr(ws, f)) for f in _WRITTEN})
+    return state, (vm, vl, vr, ve, ws.recircs)

@@ -25,8 +25,9 @@ users, heavy traffic" direction:
     ranks, each rank a fused fold→finalize→traverse (window-complete
     slots hop through ``core.inference._hop_update`` in the same
     dispatch that folded them), with empty trailing windows drained by
-    an in-jit bounded ``while_loop``.  Verdicts come back in one bulk
-    ``device_get`` per tick;
+    an in-jit bounded ``while_loop``, all on the tick's own rows
+    gathered once and written back once.  Verdicts come back per
+    column in one bulk ``device_get`` per tick;
   * the **legacy tick engine** (``tick_engine="legacy"``) keeps the
     PR-6 shape — one fold dispatch per rank, one hop dispatch + host
     sync per drain round — as the measured baseline
@@ -373,8 +374,8 @@ class ServerStats:
 
     FIELDS = ("packets", "flows_seen", "verdicts", "spilled", "evicted",
               "peak_resident", "ticks", "dispatches", "d2h_bytes",
-              "insert_probes", "lookup_probes", "lookup_keys")
-    ENGINE_DEPENDENT = ("dispatches", "d2h_bytes")
+              "insert_probes", "lookup_probes", "lookup_keys", "tick_rows")
+    ENGINE_DEPENDENT = ("dispatches", "d2h_bytes", "tick_rows")
 
     def __init__(self, registry: MetricRegistry | None = None):
         self.registry = registry if registry is not None else MetricRegistry()
@@ -401,6 +402,9 @@ class ServerStats:
         "serve_lookup_probes_total", "hash buckets examined by lookups")
     lookup_keys = _counter_stat(
         "serve_lookup_keys_total", "flow keys looked up in the table")
+    tick_rows = _counter_stat(
+        "serve_tick_rows_total",
+        "table rows the fused tick gathered and wrote back")
 
     @property
     def peak_resident(self):
@@ -631,8 +635,9 @@ class FlowTableServer:
         self._retired: set[int] = set()
         if self.tick_engine == "fused":
             # everything else lives on device (kernels.tick_step);
-            # _recircs is the host mirror refreshed by each tick's bulk
-            # verdict fetch (flush/timeout sentinels read it)
+            # _recircs is the host mirror, refreshed at each tick's
+            # columns from its bulk verdict fetch (flush/timeout
+            # sentinels read it); no other row changes on the device
             self._tstate = _tick.init_tick_state(engine.dev, N + 1, self.P)
         else:
             self._acc, self._seen = _blank_state(engine.dev, N + 1)
@@ -882,46 +887,55 @@ class FlowTableServer:
         """One jitted dispatch for the whole tick, one bulk fetch.
 
         The tick's packets are packed rank-major into ``(R, C)`` arrays
-        (column = the flow's group index, constant across ranks; unused
-        cells address the dummy row), padded on both axes to the
-        power-of-two ladder so jit compiles a handful of shapes.  The
-        retired-flow guard, IAT window reset, fold, completion hop, and
-        empty-window drain all run inside ``kernels.tick_step``.
-        ``tick/fetch/wait`` waits for the device before the copy
-        (``tick/fetch/copy``), traced or not, so a trace tells the two
-        apart.
+        (column = the flow's group index, constant across ranks), with
+        ``cols`` the slot of each column and ``n_pkts`` its packet
+        count; both axes are padded to the power-of-two ladder so jit
+        compiles a handful of shapes, and padding columns address the
+        dummy row.  The retired-flow guard, IAT window reset, fold,
+        completion hop, and empty-window drain all run inside
+        ``kernels.tick_step`` on the columns' rows, and the verdicts
+        come back per column (5 x C int32).  ``tick/fetch/wait`` waits
+        for the device before the copy (``tick/fetch/copy``), traced
+        or not, so a trace tells the two apart.
         """
         with span("tick/pack"):
             order, ss, grp_id, rank = self._rank_decompose(slots)
             R = _pow2_cap(int(rank.max()) + 1, 1)
-            C = _pow2_cap(int(grp_id[-1]) + 1, self._rank_floor)
-            slots_rc = np.full((R, C), self._dummy, np.int32)
+            n_col = int(grp_id[-1]) + 1
+            C = _pow2_cap(n_col, self._rank_floor)
+            cols = np.full(C, self._dummy, np.int32)
+            cols[grp_id] = ss
+            n_pkts = np.bincount(grp_id, minlength=C).astype(np.int32)
             pkt_rc = np.zeros((R, C, PKT_NFIELDS), np.float32)
-            slots_rc[rank, grp_id] = ss
             pkt_rc[rank, grp_id] = pkts[order]
         with span("tick/dispatch"):
             with span("tick/dispatch/put"):
-                slots_d = jnp.asarray(slots_rc)
+                cols_d = jnp.asarray(cols)
+                n_pkts_d = jnp.asarray(n_pkts)
                 pkt_d = jnp.asarray(pkt_rc)
             with span("tick/dispatch/call"):
                 self._tstate, res = _tick.tick_step(
-                    self._tstate, slots_d, pkt_d, self.engine.dev,
+                    self._tstate, cols_d, n_pkts_d, pkt_d, self.engine.dev,
                     n_subtrees=self.S, pallas=self._pallas,
                     block_b=self._block_b)
                 self.stats.dispatches += 1
+                self.stats.tick_rows += C
         with span("tick/fetch"):
             with span("tick/fetch/wait"):
                 jax.block_until_ready(res)
             with span("tick/fetch/copy"):
                 vm, vl, vr, ve, rec = self._to_host(res)
         with span("tick/evict"):
-            self._recircs = rec               # host mirror (flush/timeout)
+            # host mirror (flush/timeout): only the tick's columns can
+            # have changed on the device
+            self._recircs[cols[:n_col]] = rec[:n_col]
             done = np.nonzero(vm)[0]
             if done.size:
-                out.add_batch(self.table.key[done], vl[done], vr[done],
-                              ve[done], self._first_ts[done])
-                for slot in done:
-                    self._evict(int(slot))
+                slot = cols[done]
+                out.add_batch(self.table.key[slot], vl[done], vr[done],
+                              ve[done], self._first_ts[slot])
+                for s in slot:
+                    self._evict(int(s))
 
     def _process_resident_legacy(self, slots, fids, pkts, out) -> None:
         order, _, _, rank = self._rank_decompose(slots)

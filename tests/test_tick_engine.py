@@ -3,11 +3,15 @@ ingest tick, regardless of how many packet ranks the tick packs or how
 many drain rounds the hop loop needs.  Wall-clock on shared boxes is
 noisy; dispatch counts are deterministic, so this is the regression
 bar the cost model's call/sync terms justify."""
+import dataclasses
+import itertools
+
 import numpy as np
 import pytest
 
 from repro.core.inference import Engine, EngineOptions
 from repro.flows.synthetic import PacketBatch, make_packet_stream
+from repro.flows.windows import window_packets
 from repro.serve import FlowTableServer, StreamVerdicts
 from repro.tuning import (
     TICK_ENGINES,
@@ -243,19 +247,27 @@ def test_tick_engines_stats_agree_under_spill_and_timeout(tick_setup):
 # ---------------------------------------------------------------------------
 # transfer and probe counters
 # ---------------------------------------------------------------------------
-def test_fused_tick_copies_five_verdict_rows_per_slot(tick_setup):
-    """Each fused tick that serves resident packets copies its five
-    (N,) int32 verdict buffers to the host, and nothing else does while
-    no flow spills."""
+def test_fused_tick_copies_five_verdict_rows_per_column(tick_setup):
+    """Each fused tick that serves resident packets copies five (C,)
+    int32 verdict rows to the host, C the tick's distinct resident
+    flows padded to the power-of-two ladder (floor 64), however large
+    the table; nothing else is copied while no flow spills."""
     eng, tr, stream = tick_setup
     srv = FlowTableServer(eng, n_buckets=64, bucket_size=8,
                           tick_engine="fused")
-    N = srv.table.capacity
+    done = set()
     for b in stream.ticks(97):
-        before = srv.stats.d2h_bytes
-        srv.ingest(b)
-        assert srv.stats.d2h_bytes - before == 5 * 4 * N
+        live = np.setdiff1d(np.unique(b.flow_id), list(done))
+        C = max(64, 1 << int(live.size - 1).bit_length())
+        before, rows = srv.stats.d2h_bytes, srv.stats.tick_rows
+        v = srv.ingest(b)
+        done.update(map(int, v.flow_id))
+        assert srv.stats.tick_rows - rows == C
+        assert srv.stats.d2h_bytes - before == 5 * 4 * C
+        assert C < srv.table.capacity
     assert srv.stats.spilled == 0
+    assert srv.registry.counter("serve_tick_rows_total").value == \
+        srv.stats.tick_rows
 
 
 @pytest.fixture(scope="module")
@@ -297,3 +309,122 @@ def test_lookup_probes_agree_across_engines(probe_runs):
     assert sa.lookup_probes == sb.lookup_probes > sa.lookup_keys
     assert sa.registry.counter("serve_lookup_probes_total").value == \
         sa.lookup_probes
+
+
+# ---------------------------------------------------------------------------
+# the working-set tick: columns, not table rows, carry the rank scan
+# ---------------------------------------------------------------------------
+def _late_tail_ticks(tr):
+    """Whole flows whose flow_len undercounts their packets: after the
+    last counted packet (the verdict) copies of it keep arriving, in
+    the same tick and in the next, beside a new flow each tick."""
+    for t, i in enumerate(range(0, 24, 2)):
+        fid = np.concatenate([
+            np.full(int(tr.lengths[i]) + 3, i, np.int64),
+            np.full(int(tr.lengths[i + 1]), i + 1, np.int64),
+            np.full(2, max(i - 2, 0), np.int64)])
+        pkts = np.concatenate([
+            tr.packets[i, :int(tr.lengths[i])],
+            np.repeat(tr.packets[i, int(tr.lengths[i]) - 1][None], 3, 0),
+            tr.packets[i + 1, :int(tr.lengths[i + 1])],
+            tr.packets[max(i - 2, 0), :2]])
+        yield PacketBatch(fid, tr.lengths[fid].astype(np.int32),
+                          pkts.astype(np.float32),
+                          1e3 * t + np.arange(fid.size, dtype=np.float64))
+
+
+def _one_flow_ticks(tr):
+    """One whole flow per tick: on a one-slot table each tick's flow
+    takes the slot the previous tick's flow freed."""
+    for i in range(16):
+        fid = np.full(int(tr.lengths[i]), i, np.int64)
+        yield PacketBatch(fid, tr.lengths[fid].astype(np.int32),
+                          tr.packets[i, :fid.size].astype(np.float32),
+                          1e3 * i + np.arange(fid.size, dtype=np.float64))
+
+
+def _short(tr):
+    """The dataset cut to 1..P-1 packets a flow: every flow ends with
+    empty windows that drain inside the tick."""
+    short = np.minimum(tr.lengths, 1 + np.arange(tr.n_flows) % (P - 1))
+    return dataclasses.replace(tr, lengths=short.astype(tr.lengths.dtype))
+
+
+def _parity_case(name, tr):
+    """(dataset the verdicts come from, ticks, table shape)."""
+    if name == "whole_flows":          # ranks R = flow length
+        return tr, list(_whole_flow_ticks(tr, 16)), dict(n_buckets=64)
+    if name == "late_packets":         # the retired guard
+        return tr, list(_late_tail_ticks(tr)), dict(n_buckets=2)
+    if name == "recycled_slot":
+        return tr, list(_one_flow_ticks(tr)), dict(n_buckets=1,
+                                                   bucket_size=1)
+    sd = _short(tr)                    # "short_flows": the drain
+    stream = make_packet_stream(sd, seed=5, profile="steady")
+    return sd, list(stream.ticks(61)), dict(n_buckets=64)
+
+
+@pytest.mark.parametrize("case", ["whole_flows", "late_packets",
+                                  "recycled_slot", "short_flows"])
+def test_working_set_tick_parity(tick_setup, case):
+    """The fused tick gives the verdicts of the legacy engine and of
+    ``Engine.run`` on the rebuilt windows, flow for flow, and a retired
+    row holds no packet past its verdict."""
+    eng, tr, _ = tick_setup
+    ds, ticks, table = _parity_case(case, tr)
+    table.setdefault("bucket_size", 4)
+    want = eng.run(window_packets(ds, P), with_trace=False)
+    got = {}
+    for te in ("fused", "legacy"):
+        srv = FlowTableServer(eng, tick_engine=te, **table)
+        parts = []
+        for b in ticks:
+            parts.append(srv.ingest(b))
+            if te == "fused":
+                # a finished flow's row stops where its verdict came:
+                # packets past it never fold (the retired guard)
+                st = srv._tstate
+                done = np.asarray(st.retired) == 1
+                np.testing.assert_array_equal(
+                    np.asarray(st.pkts_seen)[done],
+                    np.asarray(st.win_hi)[done])
+        parts.append(srv.flush())
+        v = StreamVerdicts.concat(parts)
+        fid = np.asarray(v.flow_id)
+        assert np.unique(fid).size == fid.size        # one verdict a flow
+        got[te] = v
+        np.testing.assert_array_equal(
+            v.labels, np.asarray(want.labels)[fid])
+        np.testing.assert_array_equal(
+            v.recircs, np.asarray(want.recircs)[fid])
+        np.testing.assert_array_equal(
+            v.exit_partition, np.asarray(want.exit_partition)[fid])
+    np.testing.assert_array_equal(np.sort(got["fused"].flow_id),
+                                  np.sort(got["legacy"].flow_id))
+    served = {int(f) for b in ticks for f in b.flow_id}
+    assert set(map(int, got["fused"].flow_id)) == served
+
+
+def test_recircs_mirror_tracks_device_under_spill_and_timeout(tick_setup):
+    """The host ``_recircs`` mirror, written only at each tick's
+    columns, equals the device's ``recircs`` at every resident slot
+    after every tick, and the legacy engine's host array there, on a
+    tiny table (spill) with an aggressive timeout (evictions)."""
+    eng, _, stream = tick_setup
+    kw = dict(n_buckets=2, bucket_size=2, timeout=0.005)
+    fused = FlowTableServer(eng, tick_engine="fused", **kw)
+    legacy = FlowTableServer(eng, tick_engine="legacy", **kw)
+    N = fused.table.capacity
+    n_rec = 0
+    for b in itertools.islice(stream.ticks(131), 24):
+        fused.ingest(b)
+        legacy.ingest(b)
+        res = fused.table.key >= 0
+        np.testing.assert_array_equal(res, legacy.table.key >= 0)
+        dev = np.asarray(fused._tstate.recircs)[:N]
+        np.testing.assert_array_equal(fused._recircs[res], dev[res])
+        np.testing.assert_array_equal(fused._recircs[res],
+                                      legacy._recircs[res])
+        n_rec += int(dev[res].sum())
+    assert fused.stats.spilled > 0 and fused.stats.evicted > 0
+    assert n_rec > 0                   # resident flows did recirculate

@@ -21,6 +21,7 @@ Code that asks ``ops._on_tpu()`` at trace time is steered per test with
 ``monkeypatch``.
 """
 import os
+import re
 from typing import NamedTuple
 
 import jax
@@ -152,9 +153,32 @@ def test_dispatch_dt_traverse_compiles(one_chip, name):
         _spec(one_chip, (s.B,), jnp.int32), dev)
 
 
+def _instructions(text: str):
+    """``(computation, name, shape, opcode, line)`` of every instruction
+    of a compiled HLO module's text."""
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s+(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+        if m:
+            yield comp, m.group(1), m.group(2), m.group(3), line
+
+
+#: opcodes that only move or view a buffer (the non-donated write-back
+#: copies each table array before scattering into it)
+_MOVES = {"parameter", "copy", "copy-start", "copy-done", "slice-start",
+          "slice-done", "bitcast", "tuple", "get-tuple-element"}
+
+
 def test_tick_step_pallas_compiles(one_chip, on_tpu):
     """The fused serving tick at a 2^20-slot table: fold+finalize and
-    the SID-dispatched traverse both run as kernels."""
+    the SID-dispatched traverse both run as kernels, the rank scan
+    carries the tick's C columns and never the table, the only
+    computation that makes an N+1-row array is the write-back (one
+    scatter per written field), and the verdicts leave as C rows."""
     s = SHAPES["d2"]
     n1 = TABLE_SLOTS + 1
     f32, i32 = jnp.float32, jnp.int32
@@ -166,7 +190,7 @@ def test_tick_step_pallas_compiles(one_chip, on_tpu):
         recircs=col, retired=col,
         bounds=_spec(one_chip, (n1, s.P, 2), i32))
     compiled = tick.tick_step.lower(
-        state, _spec(one_chip, (TICK_RANKS, s.C), i32),
+        state, _spec(one_chip, (s.C,), i32), _spec(one_chip, (s.C,), i32),
         _spec(one_chip, (TICK_RANKS, s.C, PKT_NFIELDS), f32),
         _tables(one_chip, s), n_subtrees=s.S, pallas=True,
         block_b=ops.BLOCK_B).compile()
@@ -177,6 +201,32 @@ def test_tick_step_pallas_compiles(one_chip, on_tpu):
     assert "dt_traverse_pallas" in text
     # the table lives on the device whole: ~84 B per slot at k=4, P=3
     assert compiled.memory_analysis().argument_size_in_bytes > 80 * n1
+
+    rows = f"[{n1}"
+    insts = list(_instructions(text))
+    scattering = {c for c, _, _, op, _ in insts if op == "scatter"}
+    for comp, name, shape, op, line in insts:
+        if rows not in shape:
+            continue
+        assert op != "while", line            # the table is in no carry
+        if op == "fusion":
+            called = re.search(r"calls=%(\S+?),", line).group(1)
+            assert called in scattering, line
+        elif op == "custom-call":
+            assert "ConcatBitcast" in line, line
+        else:
+            assert op in _MOVES | {"scatter"}, line
+    writeback = [n for _, n, shape, op, _ in insts
+                 if op == "scatter" and shape[3:].startswith(rows)]
+    assert len(writeback) == len(tick._WRITTEN)
+    out = re.search(r"entry_computation_layout=\{\(.*\)->\((.*)\)\}",
+                    text.splitlines()[0]).group(1)
+    shapes = re.findall(r"[fs]32\[[\d,]*\]", out)
+    assert shapes[:len(tick.TickState._fields)] == [
+        f"f32[{n1},{s.k}]", f"s32[{n1},{s.k}]"] + [f"s32[{n1}]"] * 7 + [
+        f"s32[{n1},{s.P},2]"]
+    # verdict mask, labels, recircs, exit partition, recircs snapshot
+    assert shapes[len(tick.TickState._fields):] == [f"s32[{s.C}]"] * 5
 
 
 @pytest.mark.parametrize("compact", [False, True])
