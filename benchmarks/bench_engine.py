@@ -171,8 +171,7 @@ def run(quick: bool = True, smoke: bool = False):
         lambda: run_streaming(eng, wp, options=EngineOptions(micro_batch=mb)), repeat=repeat)
     add("engine/streaming", us_stream, B, micro_batch=mb)
 
-    from repro.distributed.sharding import flow_batch_devices
-    from repro.launch.mesh import make_flow_mesh
+    from repro.serve.mesh import flow_batch_devices, make_flow_mesh
     mesh = make_flow_mesh()
     n_mesh = flow_batch_devices(mesh)
     # the sharded path prefers a larger micro-batch (each chunk

@@ -5,7 +5,7 @@ have"; this answers the deployment question — sustained packets/sec
 through :class:`repro.serve.FlowTableServer` with verdicts emitted
 in-stream.  Rows are written to ``BENCH_serve.json`` (override with the
 BENCH_SERVE_JSON env var) alongside the CSV, one per
-``<profile>/<impl>/<tick_engine>/t<tick>`` cell:
+``<profile>/<impl>/t<tick>`` cell:
 
 * ``pkts_per_s`` — sustained ingest throughput over the whole replay
   (all ticks + flush, steady-state: jit warm-up excluded by a priming
@@ -16,18 +16,14 @@ BENCH_SERVE_JSON env var) alongside the CSV, one per
   that answer (arrival-queueing time is a property of the replayed
   trace, not of the server, so it is excluded on purpose);
 * ``dispatches_per_tick`` — jitted device calls per ingest tick.  The
-  fused tick engine's contract is O(1) (admission + tick step); the
-  legacy engine pays per rank and per drain round.  Box timings are
-  noisy — this is the deterministic column;
-* ``speedup_vs_legacy`` — fused-tick wall-clock gain over the legacy
-  engine at the same (profile, impl, tick), on fused rows where the
-  matching legacy row ran;
+  tick engine's contract is O(1) (admission + tick step).  Box timings
+  are noisy — this is the deterministic column;
 * ``max_resident_flows`` — peak concurrent flows held (table slots +
   host spill), the memory high-water mark;
 * ``spilled`` / ``evicted`` — how often the hash table overflowed to
   the host and how many flows timed out mid-stream.
 
-The fused tick engine sweeps tick sizes (64/256/1024) — bigger ticks
+The fused walk backend sweeps tick sizes (64/256/1024) — bigger ticks
 amortise the fixed two dispatches over more packets, which is the whole
 perf story on dispatch-bound hosts.  Both arrival profiles (``steady``,
 ``bursty``) run so the tail latency rows capture burst behaviour, not
@@ -127,22 +123,17 @@ def run(quick: bool = True, smoke: bool = False):
     results: list[dict] = []
     metrics_cells: dict[str, dict] = {}
     impls = ("fused", "pallas")
-    # grid: fused tick engine sweeps tick sizes; the legacy engine runs
-    # at the base tick only (it is the baseline, not the product)
-    grid = [("fused", t) for t in TICK_SWEEP] + [("legacy", base_tick)]
-    if smoke:
-        grid = [("fused", base_tick), ("fused", 4 * base_tick),
-                ("legacy", base_tick)]
-    secs_at = {}    # (profile, impl, tick, tick_engine) -> seconds
+    # grid: the fused walk backend sweeps tick sizes; pallas runs at
+    # the base tick only
+    sweep = (base_tick, 4 * base_tick) if smoke else TICK_SWEEP
     for profile in ARRIVAL_PROFILES:
         stream = make_packet_stream(tr, seed=7, profile=profile)
         for impl in impls:
-            cells = grid if impl == "fused" else [("fused", base_tick)]
-            for tick_engine, tick in cells:
-                def make_server(impl=impl, tick_engine=tick_engine):
+            ticks = sweep if impl == "fused" else (base_tick,)
+            for tick in ticks:
+                def make_server(impl=impl):
                     return FlowTableServer(
                         eng, n_buckets=buckets, bucket_size=8,
-                        tick_engine=tick_engine,
                         options=EngineOptions(impl=impl))
                 # prime jit caches with an untimed replay so the timed
                 # pass is steady-state — the capacity ladder keeps the
@@ -152,16 +143,11 @@ def run(quick: bool = True, smoke: bool = False):
 
                 secs, lat, srv = _replay(make_server, stream, tick)
                 stats = srv.stats
-                secs_at[(profile, impl, tick, tick_engine)] = secs
                 pkts_s = stats.packets / secs if secs > 0 else float("inf")
                 p50 = float(np.percentile(lat, 50) * 1e3)
                 p99 = float(np.percentile(lat, 99) * 1e3)
                 dpt = stats.dispatches / max(stats.ticks, 1)
-                legacy = secs_at.get((profile, impl, tick, "legacy"))
-                speedup = (round(legacy / secs, 2)
-                           if tick_engine == "fused" and legacy and secs > 0
-                           else None)
-                name = f"serve/{profile}/{impl}/{tick_engine}/t{tick}"
+                name = f"serve/{profile}/{impl}/t{tick}"
                 metrics_cells[name] = srv.registry.snapshot()
                 rows.append(Row(
                     name, secs / max(stats.verdicts, 1) * 1e6,
@@ -172,7 +158,6 @@ def run(quick: bool = True, smoke: bool = False):
                     "name": name,
                     "profile": profile,
                     "impl": impl,
-                    "tick_engine": tick_engine,
                     "n_flows": stats.flows_seen,
                     "n_packets": stats.packets,
                     "tick": tick,
@@ -180,21 +165,10 @@ def run(quick: bool = True, smoke: bool = False):
                     "verdict_p50_ms": round(p50, 3),
                     "verdict_p99_ms": round(p99, 3),
                     "dispatches_per_tick": round(dpt, 3),
-                    "speedup_vs_legacy": speedup,
                     "max_resident_flows": stats.peak_resident,
                     "spilled": stats.spilled,
                     "evicted": stats.evicted,
                 })
-    # the legacy baseline runs AFTER the fused sweep in each impl block;
-    # back-fill the speedup column for the fused rows it bases
-    for r in results:
-        if r["tick_engine"] != "fused" or r["speedup_vs_legacy"]:
-            continue
-        legacy = secs_at.get((r["profile"], r["impl"], r["tick"], "legacy"))
-        fused = secs_at.get((r["profile"], r["impl"], r["tick"], "fused"))
-        if legacy and fused:
-            r["speedup_vs_legacy"] = round(legacy / fused, 2)
-
     mode = "smoke" if smoke else ("quick" if quick else "full")
     path = _write_json(results, mode)
     rows.append(Row("serve/json", 0.0, f"path={path};rows={len(results)}"))

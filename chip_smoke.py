@@ -14,9 +14,9 @@ deployment size, on data generated from fixed seeds:
   ``"pallas"`` and ``"pallas"`` + ``compact=True``, then
   ``Engine.run_streaming`` (pallas);
 * serve   — ``FlowTableServer`` over a 2^20-slot table (131,072 buckets
-  x 8), ``tick_engine="fused"``, ``impl`` fused and pallas: the 65,536
-  test flows as one seeded packet stream (about 3.3 M packets) at
-  concurrency 16,384, in 32,768-packet ticks, then ``flush()``;
+  x 8), ``impl`` fused and pallas: the 65,536 test flows as one seeded
+  packet stream (about 3.3 M packets) at concurrency 16,384, in
+  32,768-packet ticks, then ``flush()``;
 * sharded — with ``--chips 4`` only: ``Engine.run_streaming`` over
   ``make_flow_mesh(4)`` against the same stream on one device.
 
@@ -158,7 +158,7 @@ def phase_serve(setup: Setup, sizes: Sizes) -> None:
     from repro.core.inference import EngineOptions
     from repro.flows.synthetic import make_packet_stream
     from repro.serve import FlowTableServer, StreamVerdicts
-    from repro.tuning import ShapeInfo, choose_tick_engine, get_plan
+    from repro.tuning import ShapeInfo, get_plan
 
     eng = setup.engine
     stream = make_packet_stream(setup.test, seed=STREAM_SEED,
@@ -167,18 +167,16 @@ def phase_serve(setup: Setup, sizes: Sizes) -> None:
     capacity = sizes.n_buckets * sizes.bucket_size
     log(f"serve: {stream.n_flows} flows, {stream.n_packets} packets, "
         f"{capacity} slots, ticks of {sizes.tick} packets")
-    # what impl="auto" / tick_engine="auto" would pick for this table
-    # (the TPU rows of the cost model are estimates, so both are pinned)
+    # what impl="auto" would pick for this table (the TPU rows of the
+    # cost model are estimates, so the walk backend is pinned)
     shape = ShapeInfo.from_engine(eng, None, B=capacity, W=1)
     plan = get_plan(eng, None, impl="auto", shape=shape,
                     backends=("fused", "pallas"), compact=False)
-    log(f"serve: auto would pick impl={plan.backend} block_b={plan.block_b} "
-        f"tick_engine={choose_tick_engine(shape, backend=plan.backend, block_b=plan.block_b)}")
+    log(f"serve: auto would pick impl={plan.backend} block_b={plan.block_b}")
     order_want = np.arange(stream.n_flows)
     for impl in ("fused", "pallas"):
         srv = FlowTableServer(eng, n_buckets=sizes.n_buckets,
                               bucket_size=sizes.bucket_size,
-                              tick_engine="fused",
                               options=EngineOptions(impl=impl))
         t0 = time.perf_counter()
         parts = [srv.ingest(batch) for batch in stream.ticks(sizes.tick)]
@@ -248,8 +246,7 @@ def phase_sharded(setup: Setup, sizes: Sizes, n_devices: int) -> dict:
     from jax.sharding import NamedSharding
 
     from repro.core.inference import PALLAS_BACKEND, EngineOptions
-    from repro.distributed.sharding import flow_batch_spec
-    from repro.launch.mesh import make_flow_mesh
+    from repro.serve.mesh import flow_batch_spec, make_flow_mesh
     from repro.serve.streaming import _sharded_walk
 
     eng = setup.engine

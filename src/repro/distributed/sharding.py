@@ -34,27 +34,6 @@ def batch_axes(mesh) -> tuple[str, ...]:
     return tuple(a for a in L.BATCH_AXES if a in names)
 
 
-def flow_batch_spec(mesh) -> P:
-    """PartitionSpec for a flow-batch tensor (leading dim = flows).
-
-    The streaming scheduler fans micro-batches out across the mesh's
-    data-parallel axes; every other dim (partition, window, packet
-    fields) stays replicated-local.  Used as the ``shard_map`` in/out
-    spec for the partition walk."""
-    axes = batch_axes(mesh)
-    if not axes:
-        raise ValueError(
-            f"mesh axes {mesh.axis_names} have no data-parallel axis "
-            f"(need one of {('pod', 'data')})")
-    return P(axes)
-
-
-def flow_batch_devices(mesh) -> int:
-    """How many ways :func:`flow_batch_spec` splits the flow axis."""
-    sizes = mesh_shape_dict(mesh)
-    return int(np.prod([sizes[a] for a in batch_axes(mesh)]))
-
-
 def batch_spec(mesh, shape: tuple[int, ...]) -> P:
     """Shard the leading (global-batch) dim over ("pod","data")."""
     sizes = mesh_shape_dict(mesh)

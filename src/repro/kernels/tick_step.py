@@ -1,16 +1,14 @@
 """Device-resident tick engine: one dispatch per serving tick.
 
-The live flow table (``repro.serve.flowtable``) used to pay one jitted
-dispatch per packet *rank* (the r-th packet of each flow in a tick) plus
-one per hop-drain round, with host round trips in between — on a CPU
-host the ~0.5 ms dispatch overhead dominated end-to-end serving latency
-(see ``tuning.costmodel.DEFAULT_COEFFS``).  This module folds the whole
-per-tick pipeline into ONE jitted call:
+The live flow table (``repro.serve.flowtable``) serves each tick —
+every packet *rank* (the r-th packet of each flow in the tick) and
+every hop-drain round — in ONE jitted call, with no host round trip
+between ranks:
 
   * **state** (:class:`TickState`) is a device-resident pytree holding
-    the per-slot window registers AND the per-flow walk metadata that
-    used to live in host numpy arrays (``sid``, ``part``, ``win_lo`` /
-    ``win_hi``, ``pkts_seen``, ``recircs``) plus a ``retired`` flag and
+    the per-slot window registers AND the per-flow walk metadata
+    (``sid``, ``part``, ``win_lo`` / ``win_hi``, ``pkts_seen``,
+    ``recircs``) plus a ``retired`` flag and
     the per-flow window ``bounds`` table.  Row ``N`` (one past the table
     capacity) is the dummy row every padded or masked scatter lands on;
   * **admission** (:func:`admit_rows`) re-initialises newly admitted
@@ -36,10 +34,10 @@ per-tick pipeline into ONE jitted call:
     table-sized buffer), maps columns to slots through ``cols`` and
     frees the finished slots host-side.
 
-Parity (docs/PARITY.md §5): every per-row computation here is the same
-row-wise kernel math the legacy per-rank path dispatched — gathers,
-masks and the column layout route rows, they never change values — so
-fused-tick verdicts are bit-identical to the host-looped path and to
+Parity (docs/PARITY.md §5): every per-row computation here is the
+row-wise kernel math of the batch walk's incremental fold, finalize
+and traverse — gathers, masks and the column layout route rows, they
+never change values — so tick verdicts are bit-identical to
 ``Engine.run`` on rebuilt windows.  A column whose rank is empty, whose
 flow already retired, or whose window did not complete keeps its row
 (``where``); padding columns read the dummy row and write it back

@@ -17,16 +17,6 @@ def make_production_mesh(*, multi_pod: bool = False):
                          axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_flow_mesh(n_data: int | None = None):
-    """1-D ("data",) mesh for flow-batch sharding (streaming engine).
-
-    ``n_data`` defaults to every visible device — the serving topology
-    where one host fans flow micro-batches out across its accelerators.
-    """
-    n = len(jax.devices()) if n_data is None else n_data
-    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
-
-
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU examples)."""
     return jax.make_mesh((data, model), ("data", "model"),

@@ -17,22 +17,17 @@ users, heavy traffic" direction:
     ids, one ``lookup_batch`` (a bucket probe of the slot-key array)
     and one ``insert_batch`` over the tick's unique flows — no
     per-packet python loop;
-  * the **fused tick engine** (``kernels.tick_step``, the default via
-    ``tick_engine="auto"``) holds ALL per-flow serving state on device
-    — window registers and the walk metadata (``sid``, partition,
-    window bounds, packets seen, recircs, retired bit) — and processes
-    one whole tick in ONE jitted dispatch: a ``lax.scan`` over packet
-    ranks, each rank a fused fold→finalize→traverse (window-complete
-    slots hop through ``core.inference._hop_update`` in the same
-    dispatch that folded them), with empty trailing windows drained by
-    an in-jit bounded ``while_loop``, all on the tick's own rows
-    gathered once and written back once.  Verdicts come back per
-    column in one bulk ``device_get`` per tick;
-  * the **legacy tick engine** (``tick_engine="legacy"``) keeps the
-    PR-6 shape — one fold dispatch per rank, one hop dispatch + host
-    sync per drain round — as the measured baseline
-    (``tuning.estimate_tick_us`` models both; ``BENCH_serve.json``
-    records the speedup).  Both engines are bit-identical;
+  * the **tick engine** (``kernels.tick_step``) holds ALL per-flow
+    serving state on device — window registers and the walk metadata
+    (``sid``, partition, window bounds, packets seen, recircs, retired
+    bit) — and processes one whole tick in ONE jitted dispatch: a
+    ``lax.scan`` over packet ranks, each rank a fused
+    fold→finalize→traverse (window-complete slots hop through
+    ``core.inference._hop_update`` in the same dispatch that folded
+    them), with empty trailing windows drained by an in-jit bounded
+    ``while_loop``, all on the tick's own rows gathered once and
+    written back once.  Verdicts come back per column in one bulk
+    ``device_get`` per tick;
   * **timeout eviction** emits mid-stream verdicts for idle flows with
     the ``-1`` sentinel convention (labels / exit_partition), keeping
     the accumulated recirculation count.
@@ -46,43 +41,32 @@ order — the reduction order the parity contract pins — is preserved.
 Rank batches are padded to a power-of-two capacity ladder (a dummy
 table row absorbs the padding) so jit compiles a handful of shapes,
 not one per tick.  ``ServerStats.dispatches`` counts jitted device
-calls: the fused tick engine issues at most 2 per tick (admission
-scatter + tick step) regardless of rank count or drain rounds — the
-deterministic perf bar ``tests/test_tick_engine.py`` pins.
+calls: a tick issues at most 2 (admission scatter + tick step)
+regardless of rank count or drain rounds — the deterministic perf bar
+``tests/test_tick_engine.py`` pins.
 
 Execution knobs come from :class:`repro.core.inference.EngineOptions`:
 ``impl`` picks the fold/traverse kernels (``fused`` = dense jnp,
 ``pallas`` = the Pallas scatter-update + SID-dispatched traverse;
 ``auto``/``tuned`` resolve a ``repro.tuning.Plan`` for the table
-shape), ``block_b`` the Pallas block size; ``tick_engine="auto"`` then
-routes fused-tick vs legacy through the tick-shape cost estimate
-(``repro.tuning.choose_tick_engine``).  All routes are bit-identical
-to ``Engine.run`` on the offline windows — the flow table can only
-change *when* a verdict is computed, never its value.
+shape), ``block_b`` the Pallas block size.  Every route is
+bit-identical to ``Engine.run`` on the offline windows — the flow
+table can only change *when* a verdict is computed, never its value.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.features import PKT_IAT, PKT_NFIELDS
-from repro.core.inference import Engine, EngineOptions, _hop_update
+from repro.core.inference import Engine, EngineOptions
 from repro.flows.windows import window_bounds
-from repro.kernels import ops
-from repro.kernels import ref as _ref
 from repro.kernels import tick_step as _tick
-from repro.kernels.dispatch import dispatch_dt_traverse
 from repro.kernels.dt_traverse import BLOCK_B
-from repro.kernels.feature_window import feature_update_at
 from repro.obs import MetricRegistry, exp_edges, span
-
-#: Tick-engine modes ``FlowTableServer`` accepts ("auto" resolves via
-#: the tick-shape cost estimate in ``repro.tuning``).
-TICK_ENGINES = ("auto", "fused", "legacy")
 
 #: Histogram bucket edges (docs/OBSERVABILITY.md catalogues the
 #: metrics).  TTD is measured in STREAM time — the packet arrival
@@ -365,17 +349,11 @@ class ServerStats:
     properties over the registry, so stats appear in Prometheus/JSONL
     exposition for free.  ``ServerStats()`` with no argument gets a
     private registry.
-
-    ``ENGINE_DEPENDENT`` names the fields that differ between the
-    fused and legacy tick engines by design (how many device calls
-    and device->host copies serve the same ticks); every other field
-    is the same for both.
     """
 
     FIELDS = ("packets", "flows_seen", "verdicts", "spilled", "evicted",
               "peak_resident", "ticks", "dispatches", "d2h_bytes",
               "insert_probes", "lookup_probes", "lookup_keys", "tick_rows")
-    ENGINE_DEPENDENT = ("dispatches", "d2h_bytes", "tick_rows")
 
     def __init__(self, registry: MetricRegistry | None = None):
         self.registry = registry if registry is not None else MetricRegistry()
@@ -404,7 +382,7 @@ class ServerStats:
         "serve_lookup_keys_total", "flow keys looked up in the table")
     tick_rows = _counter_stat(
         "serve_tick_rows_total",
-        "table rows the fused tick gathered and wrote back")
+        "table rows the tick gathered and wrote back")
 
     @property
     def peak_resident(self):
@@ -426,89 +404,15 @@ class ServerStats:
 
 
 # ---------------------------------------------------------------------------
-# jitted device steps (module level: compile cache shared across servers)
+# execution resolution
 # ---------------------------------------------------------------------------
 def _pow2_cap(n: int, floor: int) -> int:
-    """Smallest power-of-two >= n (>= floor) — the rank/hop batch
+    """Smallest power-of-two >= n (>= floor) — the tick's rank/column
     capacity ladder, so jit sees a handful of shapes per table."""
     cap = max(int(floor), 1)
     while cap < n:
         cap *= 2
     return cap
-
-
-@functools.partial(jax.jit, static_argnames=("n",))
-def _blank_state(dev: ops.DeviceTables, n: int):
-    """(acc, seen) for ``n`` rows, initialised for the root SID 0."""
-    op = jnp.broadcast_to(dev.slot_op[0][None, :],
-                          (n, dev.slot_op.shape[1]))
-    return _ref.feature_state_init(op)
-
-
-@jax.jit
-def _reset_rows(acc, seen, slots, sid_rows, dev):
-    """Re-initialise the addressed rows for their (new) SID's ops."""
-    a0, s0 = _ref.feature_state_init(dev.slot_op[sid_rows])
-    return acc.at[slots].set(a0), seen.at[slots].set(s0)
-
-
-@functools.partial(jax.jit, static_argnames=("pallas", "block_b"))
-def _fold_rank(acc, seen, pkt, sid_rows, slots, dev, *,
-               pallas: bool, block_b: int):
-    """Fold one rank (<= 1 packet per slot) into the resident state.
-
-    Padding entries address the dummy row with an invalid packet; all
-    compute identical values, so the duplicate scatter is
-    deterministic.
-    """
-    op = dev.slot_op[sid_rows]
-    fld = dev.slot_field[sid_rows]
-    prd = dev.slot_pred[sid_rows]
-    if pallas:
-        return feature_update_at(acc, seen, slots, pkt, op, fld, prd,
-                                 interpret=not ops._on_tpu(),
-                                 block_b=block_b)
-    a2, s2 = _ref.feature_update_ref(pkt, op, fld, prd,
-                                     acc[slots], seen[slots])
-    return acc.at[slots].set(a2), seen.at[slots].set(s2)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_subtrees", "pallas", "block_b"))
-def _hop_rank(acc, seen, slots, sid_rows, p_rows, rec_rows, dev, *,
-              n_subtrees: int, pallas: bool, block_b: int):
-    """One recirculation hop for the slots whose window just completed.
-
-    Finalize the folded registers, traverse the active subtree, and run
-    the walk's own ``_hop_update`` bookkeeping with this batch's
-    per-flow partition indices; the hopped rows are re-initialised for
-    their post-hop SID (exited rows are reset too — harmless, their
-    slots are freed host-side).  Returns the updated state tables plus
-    ``(labels, done, sid, recircs, exit_partition)`` for the host.
-    """
-    op = dev.slot_op[sid_rows]
-    init = dev.slot_init[sid_rows]
-    regs = _ref.feature_finalize_ref(acc[slots], seen[slots], op, init)
-    if pallas:
-        action = dispatch_dt_traverse(
-            regs, sid_rows, dev.thresholds, dev.leaf_lo, dev.leaf_hi,
-            dev.leaf_action, dev.leaf_valid,
-            interpret=not ops._on_tpu(), block_b=block_b)
-    else:
-        action = _ref.dt_traverse_ref(
-            regs, dev.thresholds[sid_rows], dev.leaf_lo[sid_rows],
-            dev.leaf_hi[sid_rows], dev.leaf_action[sid_rows],
-            dev.leaf_valid[sid_rows] > 0)
-    carry = (sid_rows,
-             jnp.zeros(sid_rows.shape, jnp.bool_),
-             jnp.full(sid_rows.shape, -1, jnp.int32),
-             rec_rows,
-             jnp.full(sid_rows.shape, -1, jnp.int32))
-    sid2, done, labels, rec2, exit_p = _hop_update(
-        carry, p_rows, action, n_subtrees)
-    a0, s0 = _ref.feature_state_init(dev.slot_op[sid2])
-    return (acc.at[slots].set(a0), seen.at[slots].set(s0),
-            labels, done, sid2, rec2, exit_p)
 
 
 def _resolve_exec(engine: Engine, opt: EngineOptions, capacity: int):
@@ -556,12 +460,9 @@ class FlowTableServer:
     than ``timeout`` seconds of stream time are evicted at tick
     boundaries the same way.
 
-    ``tick_engine`` picks the per-tick execution strategy: ``"fused"``
-    runs one jitted tick step for the whole rank loop + hop drain
-    (``kernels.tick_step``), ``"legacy"`` dispatches per rank / per
-    drain round, ``"auto"`` (default) routes through the tick-shape
-    cost estimate — fused everywhere dispatch overhead dominates.
-    Both are bit-identical; only dispatch counts and latency differ.
+    Each tick runs as one jitted tick step for the whole rank loop and
+    hop drain (``kernels.tick_step``).  ``tick_engine`` accepts only
+    ``"fused"``, that engine's name; any other value raises.
 
     Each flow key is served exactly once: after its verdict (exit,
     flush, or timeout) the key is retired and late packets for it are
@@ -573,7 +474,7 @@ class FlowTableServer:
     def __init__(self, engine: Engine, *, n_buckets: int = 64,
                  bucket_size: int = 8, timeout: float | None = None,
                  options: EngineOptions | None = None,
-                 rank_floor: int = 64, tick_engine: str = "auto",
+                 rank_floor: int = 64, tick_engine: str = "fused",
                  registry: MetricRegistry | None = None):
         self.engine = engine
         self.options = options or EngineOptions()
@@ -584,17 +485,9 @@ class FlowTableServer:
         self._rank_floor = int(rank_floor)
         self._pallas, self._block_b, self._plan = _resolve_exec(
             engine, self.options, self.table.capacity)
-        if tick_engine not in TICK_ENGINES:
+        if tick_engine != "fused":
             raise ValueError(f"unknown tick_engine {tick_engine!r}; "
-                             f"options {TICK_ENGINES}")
-        if tick_engine == "auto":
-            from repro.tuning import ShapeInfo, choose_tick_engine
-            shape = ShapeInfo.from_engine(engine, None,
-                                          B=self.table.capacity, W=1)
-            tick_engine = choose_tick_engine(
-                shape, backend="pallas" if self._pallas else "fused",
-                block_b=self._block_b)
-        self.tick_engine = tick_engine
+                             "the one tick engine is 'fused'")
         # spilled flows run the batch walk; pin the same backend family
         self._spill_options = EngineOptions(
             impl="pallas" if self._pallas else "fused",
@@ -633,20 +526,11 @@ class FlowTableServer:
         self._recircs = np.zeros(N, np.int32)
         self._spill: dict[int, _SpillFlow] = {}
         self._retired: set[int] = set()
-        if self.tick_engine == "fused":
-            # everything else lives on device (kernels.tick_step);
-            # _recircs is the host mirror, refreshed at each tick's
-            # columns from its bulk verdict fetch (flush/timeout
-            # sentinels read it); no other row changes on the device
-            self._tstate = _tick.init_tick_state(engine.dev, N + 1, self.P)
-        else:
-            self._acc, self._seen = _blank_state(engine.dev, N + 1)
-            self._sid = np.zeros(N, np.int32)
-            self._part = np.zeros(N, np.int32)
-            self._win_lo = np.zeros(N, np.int32)
-            self._win_hi = np.zeros(N, np.int32)
-            self._pkts_seen = np.zeros(N, np.int32)
-            self._bounds = np.zeros((N, self.P, 2), np.int32)
+        # everything else lives on device (kernels.tick_step);
+        # _recircs is the host mirror, refreshed at each tick's columns
+        # from its bulk verdict fetch (flush/timeout sentinels read it);
+        # no other row changes on the device
+        self._tstate = _tick.init_tick_state(engine.dev, N + 1, self.P)
 
     # -- admission ------------------------------------------------------
     @property
@@ -717,38 +601,20 @@ class FlowTableServer:
         lengths = np.maximum(np.asarray(lengths, np.int64), 1)
         self._last_ts[slots] = -np.inf
         self._first_ts[slots] = np.inf        # new tenant: fresh TTD clock
-        if self.tick_engine == "fused":
-            cap, padded = self._pad_slots(slots)
-            plen = np.ones(cap, np.int32)
-            plen[:slots.size] = lengths
-            self._tstate = _tick.admit_rows(
-                self._tstate, jnp.asarray(padded), jnp.asarray(plen),
-                self.engine.dev)
-            self.stats.dispatches += 1
-            return
-        # legacy: host metadata writes (vectorized) + one device reset
-        P = self.P
-        length = lengths.astype(np.int32)
-        base = np.maximum(length // P, 1)
-        w = np.arange(P, dtype=np.int32)[None, :]
-        lo = np.minimum(w * base[:, None], length[:, None])
-        hi = np.minimum((w + 1) * base[:, None], length[:, None])
-        hi[:, P - 1] = length
-        self._bounds[slots] = np.stack([lo, hi], axis=-1)
-        self._sid[slots] = 0
-        self._part[slots] = 0
-        self._win_lo[slots] = lo[:, 0]
-        self._win_hi[slots] = hi[:, 0]
-        self._pkts_seen[slots] = 0
-        self._recircs[slots] = 0
-        self._reset_admitted(np.sort(slots))
+        cap, padded = self._pad_slots(slots)
+        plen = np.ones(cap, np.int32)
+        plen[:slots.size] = lengths
+        self._tstate = _tick.admit_rows(
+            self._tstate, jnp.asarray(padded), jnp.asarray(plen),
+            self.engine.dev)
+        self.stats.dispatches += 1
 
     # -- ingest ---------------------------------------------------------
     def ingest(self, batch) -> StreamVerdicts:
         """Fold one tick of packet arrivals; return completed verdicts.
 
-        On the fused tick engine every host statement of the call lies
-        under exactly one leaf span, and the children of a span run one
+        Every host statement of the call lies under exactly one leaf
+        span, and the children of a span run one
         after another (docs/OBSERVABILITY.md draws the tree):
         ``tick/stamp`` opens twice, before admission (the batch as
         arrays, counters, stream clock) and after it (spill rows,
@@ -790,11 +656,7 @@ class FlowTableServer:
                     pkts = pk[res_rows]
 
             if res_rows.size:
-                if self.tick_engine == "fused":
-                    self._process_resident_fused(slots, pkts, out)
-                else:
-                    self._process_resident_legacy(slots, fid[res_rows],
-                                                  pkts, out)
+                self._process_resident_fused(slots, pkts, out)
             with span("tick/spill"):
                 self._run_spilled_complete(out)
             if self.timeout is not None and n:
@@ -827,9 +689,9 @@ class FlowTableServer:
         """Build the tick's verdicts and fold them into the registry.
 
         Everything here is derived from the verdicts themselves plus
-        the stream clock, so it is deterministic across replays and
-        across tick engines — the live-parity tests recompute each
-        value offline from the raw :class:`StreamVerdicts`.
+        the stream clock, so it is deterministic across replays — the
+        live-parity tests recompute each value offline from the raw
+        :class:`StreamVerdicts`.
         """
         v = out.build(self._plan)
         if v.n_flows:
@@ -858,13 +720,6 @@ class FlowTableServer:
         host = [np.asarray(a) for a in jax.device_get(arrays)]
         self.stats.d2h_bytes += sum(a.nbytes for a in host)
         return host
-
-    def _reset_admitted(self, s: np.ndarray) -> None:
-        cap, slots = self._pad_slots(s)
-        self._acc, self._seen = _reset_rows(
-            self._acc, self._seen, jnp.asarray(slots),
-            jnp.zeros(cap, jnp.int32), self.engine.dev)
-        self.stats.dispatches += 1
 
     @staticmethod
     def _rank_decompose(slots: np.ndarray):
@@ -937,87 +792,6 @@ class FlowTableServer:
                 for s in slot:
                     self._evict(int(s))
 
-    def _process_resident_legacy(self, slots, fids, pkts, out) -> None:
-        order, _, _, rank = self._rank_decompose(slots)
-        for r in range(int(rank.max()) + 1):
-            sel = order[rank == r]
-            s = slots[sel]
-            # a flow that exited earlier this tick frees its slot; any
-            # later packets of it (malformed flow_len) must not fold
-            # into the slot's next tenant
-            alive = self.table.key[s] == fids[sel]
-            sel, s = sel[alive], s[alive]
-            if not s.size:
-                continue
-            p = pkts[sel].copy()
-            # window boundary clears the dependency chain (first-packet
-            # IAT = 0), matching flows.windows.window_packets
-            p[self._pkts_seen[s] == self._win_lo[s], PKT_IAT] = 0.0
-            self._fold(s, p)
-            self._pkts_seen[s] += 1
-            complete = s[self._pkts_seen[s] == self._win_hi[s]]
-            if complete.size:
-                self._hop_drain(complete, out)
-
-    def _fold(self, s: np.ndarray, p: np.ndarray) -> None:
-        cap, slots = self._pad_slots(s)
-        sid = np.zeros(cap, np.int32)
-        sid[:s.size] = self._sid[s]
-        pkt = np.zeros((cap, PKT_NFIELDS), np.float32)
-        pkt[:s.size] = p
-        with span("tick/dispatch"):
-            self._acc, self._seen = _fold_rank(
-                self._acc, self._seen, jnp.asarray(pkt), jnp.asarray(sid),
-                jnp.asarray(slots), self.engine.dev,
-                pallas=self._pallas, block_b=self._block_b)
-            self.stats.dispatches += 1
-
-    def _hop_drain(self, s: np.ndarray, out: _VerdictAccum) -> None:
-        """Hop the completed slots; drain any windows that complete
-        immediately after (flows shorter than P packets have empty
-        trailing windows — the walk still traverses them, so we do
-        too).  Terminates: every drain round advances the partition.
-        Per-slot bookkeeping is vectorized with numpy masks."""
-        while s.size:
-            cap, slots = self._pad_slots(s)
-            sid = np.zeros(cap, np.int32)
-            sid[:s.size] = self._sid[s]
-            p_rows = np.zeros(cap, np.int32)
-            p_rows[:s.size] = self._part[s]
-            rec = np.zeros(cap, np.int32)
-            rec[:s.size] = self._recircs[s]
-            with span("tick/dispatch"):
-                res = _hop_rank(
-                    self._acc, self._seen, jnp.asarray(slots),
-                    jnp.asarray(sid), jnp.asarray(p_rows),
-                    jnp.asarray(rec),
-                    self.engine.dev, n_subtrees=self.S,
-                    pallas=self._pallas, block_b=self._block_b)
-                self.stats.dispatches += 1
-            self._acc, self._seen = res[0], res[1]
-            with span("tick/fetch"):
-                labels, done, sid2, rec2, exit_p = (
-                    a[:s.size] for a in self._to_host(res[2:]))
-            done = done.astype(bool)
-            # exits emit verdicts; flows falling off the last partition
-            # emit -1 sentinels; the rest advance to the next window
-            fin = done | (self._part[s] == self.P - 1)
-            if fin.any():
-                out.add_batch(self.table.key[s[fin]],
-                              np.where(done, labels, -1)[fin], rec2[fin],
-                              np.where(done, exit_p, -1)[fin],
-                              self._first_ts[s[fin]])
-                for slot in s[fin]:
-                    self._evict(int(slot))
-            sa = s[~fin]
-            self._sid[sa] = sid2[~fin]
-            self._recircs[sa] = rec2[~fin]
-            self._part[sa] += 1
-            b = self._bounds[sa, self._part[sa]]
-            self._win_lo[sa] = b[:, 0]
-            self._win_hi[sa] = b[:, 1]
-            s = sa[b[:, 0] == b[:, 1]]        # empty window: hop again
-
     # -- host fallbacks -------------------------------------------------
     def _run_spilled_complete(self, out: _VerdictAccum) -> None:
         """Run completed spilled flows through the batch walk."""
@@ -1028,13 +802,15 @@ class FlowTableServer:
         P = self.P
         all_bounds = {key: window_bounds(self._spill[key].length, P)
                       for key in done}
-        w_max = max(1, max(hi - lo for b in all_bounds.values()
-                           for lo, hi in b))
-        # pad the flows axis to the pow2 capacity ladder: batch rows are
-        # independent in the walk, so the zero-filled tail is discarded
-        # below.  Without this, every distinct spill-batch size is a
-        # fresh XLA compile — a spill-heavy stream (tiny table) racks up
-        # one executable per tick and can OOM the compiler.
+        # pad both the flows axis and the window axis to the pow2
+        # ladder: batch rows are independent in the walk (the
+        # zero-filled tail is discarded below) and padded packets carry
+        # valid=0, as in ``window_packets``.  Without this, every
+        # distinct spill-batch shape is a fresh XLA compile — a
+        # spill-heavy stream (tiny table) racks up one executable per
+        # tick and can OOM the compiler.
+        w_max = _pow2_cap(max(hi - lo for b in all_bounds.values()
+                              for lo, hi in b), 1)
         cap = _pow2_cap(len(done), 1)
         wp = np.zeros((cap, P, w_max, PKT_NFIELDS), np.float32)
         for idx, key in enumerate(done):
@@ -1047,9 +823,7 @@ class FlowTableServer:
                 wp[idx, w, :hi - lo] = win
         res = self.engine.run(wp, with_trace=False,
                               options=self._spill_options)
-        # the batch walk is a jitted device call like any tick step;
-        # both tick engines share this path, so counting it keeps
-        # fused/legacy dispatch counts comparable
+        # the batch walk is a jitted device call like any tick step
         self.stats.dispatches += 1
         labels, recircs, exit_p = self._to_host(
             (res.labels, res.recircs, res.exit_partition))

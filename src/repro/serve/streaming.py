@@ -73,9 +73,9 @@ from repro.core.inference import (
     pallas_backend,
     partition_walk,
 )
-from repro.distributed.sharding import flow_batch_devices, flow_batch_spec
 from repro.kernels.compaction import COMPACT_FLOOR
 from repro.kernels.dispatch import pad_axis0, round_up
+from repro.serve.mesh import flow_batch_devices, flow_batch_spec
 
 
 def _walk_backend(engine: Engine, impl: str | None) -> ExecutionBackend:

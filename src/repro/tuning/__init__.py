@@ -21,18 +21,13 @@ from repro.tuning.autotune import (  # noqa: F401
 from repro.tuning.costmodel import (  # noqa: F401
     BACKENDS,
     BLOCK_B_CANDIDATES,
-    TICK_ENGINES,
     Coefficients,
     Plan,
     ShapeInfo,
     calibrate,
     candidate_plans,
     choose_plan,
-    choose_tick_engine,
-    choose_tick_plan,
-    estimate_tick_us,
     estimate_us,
     fit_coefficients,
-    tick_work_terms,
     work_terms,
 )

@@ -376,120 +376,6 @@ def estimate_us(shape: ShapeInfo, plan: Plan,
 
 
 # ---------------------------------------------------------------------------
-# serving tick estimate (the flow-table server's per-ingest shape)
-# ---------------------------------------------------------------------------
-#: Tick-engine families the flow-table server routes between: "fused"
-#: runs the whole rank loop + hop drain inside one jitted tick step
-#: (kernels.tick_step), "legacy" dispatches per rank and per drain
-#: round with a host sync in between.
-TICK_ENGINES = ("fused", "legacy")
-
-
-def tick_work_terms(shape: ShapeInfo, plan: Plan, *, ranks: int = 4,
-                    drains: float = 1.0,
-                    tick_engine: str = "fused") -> np.ndarray:
-    """Per-:data:`TERMS` work units for ONE flow-table ingest tick.
-
-    ``shape.B`` is the padded rank width (slots touched per tick),
-    ``shape.W`` should be 1 (the incremental fold sees one packet per
-    slot per rank), ``ranks`` the tick's rank-chain depth (max packets
-    of any one flow), and ``drains`` the expected extra hop rounds from
-    empty trailing windows.  The per-rank *work* terms are identical
-    for both tick engines — only the dispatch/sync pattern differs:
-
-    * ``legacy`` — one admission reset + one fold call per rank + one
-      hop call **and host sync** per traverse round;
-    * ``fused``  — one admission scatter + ONE tick-step call + ONE
-      bulk verdict fetch, whatever the rank count or drain depth.
-
-    On a CPU host the ~0.5 ms ``call`` coefficient makes the fused tick
-    the winner for every non-trivial tick; the term split keeps the
-    decision honest if the coefficients are refit on hardware where
-    dispatch is cheap and the scan's serialization might matter.
-    """
-    if tick_engine not in TICK_ENGINES:
-        raise ValueError(f"unknown tick engine {tick_engine!r}; "
-                         f"options {TICK_ENGINES}")
-    s, k = shape, shape.k
-    unit = k * s.T + s.L * k
-    gather = k * s.T + 2 * s.L * k + 2 * s.L
-    B = max(int(s.B), 1)
-    hops = ranks + drains                        # traverse rounds / tick
-    w = dict.fromkeys(TERMS, 0.0)
-    if tick_engine == "legacy":
-        w["call"] = 1.0 + ranks + hops
-        w["sync"] = float(hops)
-    else:
-        w["call"] = 2.0
-        w["sync"] = 1.0
-    w["fw"] = float(ranks) * B * k               # one packet per fold
-    if plan.backend == "pallas":
-        bb = plan.block_b
-        nb = capacity_blocks(B, s.S, bb)
-        fw_blocks = -(-B // min(bb, B))
-        w["grid"] = ranks * fw_blocks + hops * nb
-        w["sort"] = hops * B * math.log2(max(B, 2))
-        w["tr_pallas"] = hops * nb * bb * unit
-    else:
-        w["tr_dense"] = hops * B * (unit + gather)
-    return _vec(w)
-
-
-def estimate_tick_us(shape: ShapeInfo, plan: Plan, *, ranks: int = 4,
-                     drains: float = 1.0, tick_engine: str = "fused",
-                     coeffs: Coefficients | None = None) -> float:
-    """Model estimate (μs per ingest tick) for the flow-table server."""
-    c = coeffs or default_coefficients(plan.backend)
-    return float(tick_work_terms(shape, plan, ranks=ranks, drains=drains,
-                                 tick_engine=tick_engine) @ c.vector())
-
-
-def choose_tick_engine(shape: ShapeInfo, *, ranks: int = 4,
-                       drains: float = 1.0, backend: str = "fused",
-                       block_b: int = BLOCK_B,
-                       coeffs: Coefficients | None = None) -> str:
-    """Pick fused-tick vs legacy per-rank serving for a table shape.
-
-    Used by ``FlowTableServer(tick_engine="auto")`` once the walk
-    backend/block size are resolved (``impl="auto"``/``"tuned"``).
-    Pure arithmetic, ties go to fused (fewer dispatches can only help
-    the tail).
-    """
-    plan = Plan(backend=backend, block_b=block_b)
-    kw = dict(ranks=ranks, drains=drains, coeffs=coeffs)
-    fused = estimate_tick_us(shape, plan, tick_engine="fused", **kw)
-    legacy = estimate_tick_us(shape, plan, tick_engine="legacy", **kw)
-    return "fused" if fused <= legacy else "legacy"
-
-
-def choose_tick_plan(
-    shape: ShapeInfo, *, ranks: int = 4, drains: float = 1.0,
-    backends: Sequence[str] = ("fused", "pallas"),
-    coeffs: dict[str, Coefficients] | None = None,
-) -> tuple[str, Plan]:
-    """Argmin (tick_engine, walk plan) for one serving tick shape.
-
-    The serving analogue of :func:`choose_plan`: sweeps the walk
-    backends × ``BLOCK_B_CANDIDATES`` × both tick engines and returns
-    the cheapest combination — how the tick-shape estimate picks
-    ``block_b`` for the table shape alongside the engine.
-    """
-    best = None
-    best_us = float("inf")
-    for te in TICK_ENGINES:
-        for plan in candidate_plans(shape, backends=backends,
-                                    compact=False):
-            c = (coeffs or {}).get(plan.backend) if coeffs else None
-            us = estimate_tick_us(shape, plan, ranks=ranks, drains=drains,
-                                  tick_engine=te, coeffs=c)
-            if us < best_us:
-                best, best_us = (te, plan), us
-    te, plan = best
-    return te, dataclasses.replace(plan, source="costmodel",
-                                   est_us=round(best_us, 1))
-
-
-# ---------------------------------------------------------------------------
 # plan enumeration + selection
 # ---------------------------------------------------------------------------
 def candidate_plans(

@@ -85,8 +85,8 @@ def test_incremental_fold_matches_rebuilt_window(serve_setup, impl):
 def test_fused_update_finalize_matches_composition(serve_setup, impl):
     """The tick-step kernel fuses fold and finalize into one pass; its
     registers AND its carried (acc, seen) must be bit-identical to the
-    two-call composition at every packet position — otherwise the fused
-    tick engine would drift from the legacy per-rank dispatches."""
+    two-call composition at every packet position — otherwise the tick
+    engine would drift from the batch walk's window registers."""
     eng, tr, wp, _, _ = serve_setup
     dev = eng.dev
     B, _, W, _ = wp.shape
@@ -256,7 +256,6 @@ def test_whole_flow_ticks_recycle_slots(serve_setup, impl):
     eng, tr, _, full, _ = serve_setup
     fids = list(range(24))
     srv = FlowTableServer(eng, n_buckets=1, bucket_size=1, rank_floor=1,
-                          tick_engine="fused",
                           options=EngineOptions(impl=impl))
     parts = [srv.ingest(_flow_batch(tr, (i, i + 1), t0=1e3 * i))
              for i in range(0, 24, 2)]
@@ -266,12 +265,12 @@ def test_whole_flow_ticks_recycle_slots(serve_setup, impl):
     _assert_subset_matches(v, full, fids)
 
 
-@pytest.mark.parametrize("tick_engine", ["fused", "legacy"])
-def test_interleaved_boundary_hops_mid_tick(serve_setup, tick_engine):
+@pytest.mark.parametrize("impl", ["fused", "pallas"])
+def test_interleaved_boundary_hops_mid_tick(serve_setup, impl):
     """Round-robin interleave of 16 flows in ONE tick: every window
     boundary, hop, and drain round lands mid-tick, with many flows
     completing in the same rank — the worst case for the in-jit hop
-    bookkeeping (fused) and the vectorized drain masks (legacy)."""
+    bookkeeping, on either walk backend."""
     eng, tr, _, full, _ = serve_setup
     sel = list(range(40, 56))
     maxlen = max(int(tr.lengths[i]) for i in sel)
@@ -286,14 +285,13 @@ def test_interleaved_boundary_hops_mid_tick(serve_setup, tick_engine):
                         np.asarray(pkt_rows, np.float32),
                         np.arange(fid.size, dtype=np.float64))
     srv = FlowTableServer(eng, n_buckets=4, bucket_size=4,
-                          tick_engine=tick_engine)
+                          options=EngineOptions(impl=impl))
     v = StreamVerdicts.concat([srv.ingest(batch), srv.flush()])
     _assert_subset_matches(v, full, sel)
 
 
-@pytest.mark.parametrize("tick_engine", ["fused", "legacy"])
-def test_late_packets_cannot_corrupt_recycled_slot(serve_setup,
-                                                   tick_engine):
+@pytest.mark.parametrize("impl", ["fused", "pallas"])
+def test_late_packets_cannot_corrupt_recycled_slot(serve_setup, impl):
     """A flow completes mid-tick, duplicate tail packets of it keep
     arriving in the SAME tick (must not fold into anything), then a new
     flow takes the freed slot next tick while yet more late packets of
@@ -301,7 +299,7 @@ def test_late_packets_cannot_corrupt_recycled_slot(serve_setup,
     eng, tr, _, full, _ = serve_setup
     a, b = 3, 5
     srv = FlowTableServer(eng, n_buckets=1, bucket_size=1,
-                          tick_engine=tick_engine)
+                          options=EngineOptions(impl=impl))
     v1 = srv.ingest(_flow_batch(tr, [a], extra_tail=3))
     assert v1.n_flows == 1                # a completed despite the dups
     t2 = _flow_batch(tr, [b], t0=1e6, extra_tail=0)
@@ -356,7 +354,6 @@ def test_flowtable_padding_never_leaks(seed):
         options=EngineOptions(
             impl=("fused", "pallas")[int(rng.integers(0, 2))]),
         rank_floor=int(rng.integers(1, 65)),
-        tick_engine=("fused", "legacy")[int(rng.integers(0, 2))],
     )
     v = _serve_all(srv, stream, tick=int(rng.integers(1, 300)))
     _assert_verdicts_match(v, full, tr.n_flows)

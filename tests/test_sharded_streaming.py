@@ -13,7 +13,7 @@ from repro.core.inference import Engine
 from repro.core.partition import train_partitioned_dt
 from repro.flows.synthetic import make_dataset
 from repro.flows.windows import window_features, window_packets
-from repro.launch.mesh import make_flow_mesh
+from repro.serve.mesh import make_flow_mesh
 from repro.serve.streaming import run_streaming
 
 ds = make_dataset("d2", n_flows=600)

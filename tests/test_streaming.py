@@ -12,6 +12,7 @@ from repro.flows.windows import window_features, window_packets
 from repro.serve.streaming import microbatches, run_streaming, stream_batches
 from repro.testing.hypothesis_compat import given, settings, strategies as st
 from repro.core.inference import EngineOptions
+from tests.conftest import run_subprocess
 
 
 @pytest.fixture(scope="module")
@@ -170,3 +171,21 @@ def test_streaming_padding_never_leaks_property(seed):
         np.testing.assert_array_equal(res.recircs, full.recircs)
         np.testing.assert_array_equal(res.exit_partition,
                                       full.exit_partition)
+
+
+#: Subpackages of the language-model prototype that shares ``src/repro``.
+_LM_TREE = ("models", "configs", "train", "launch", "distributed", "data",
+            "analysis")
+
+
+def test_serving_path_imports_no_lm_module():
+    """The serving layer stands alone: a fresh interpreter that imports
+    ``repro.serve`` has loaded no module of the LM prototype."""
+    out = run_subprocess(
+        "import sys, repro.serve\n"
+        "print(' '.join(sorted(m for m in sys.modules "
+        "if m.startswith('repro.'))))", devices=1)
+    loaded = out.split()
+    lm = [m for m in loaded if m.split(".")[1] in _LM_TREE]
+    assert not lm, lm
+    assert "repro.serve.streaming" in loaded

@@ -1,8 +1,8 @@
-"""The fused tick engine's perf contract: O(1) device dispatches per
-ingest tick, regardless of how many packet ranks the tick packs or how
-many drain rounds the hop loop needs.  Wall-clock on shared boxes is
-noisy; dispatch counts are deterministic, so this is the regression
-bar the cost model's call/sync terms justify."""
+"""The tick engine's contracts: O(1) device dispatches per ingest tick,
+regardless of how many packet ranks the tick packs or how many drain
+rounds the hop loop needs (wall-clock on shared boxes is noisy;
+dispatch counts are deterministic), and verdicts equal to
+``Engine.run`` on the rebuilt windows on either walk backend."""
 import dataclasses
 import itertools
 
@@ -13,14 +13,6 @@ from repro.core.inference import Engine, EngineOptions
 from repro.flows.synthetic import PacketBatch, make_packet_stream
 from repro.flows.windows import window_packets
 from repro.serve import FlowTableServer, StreamVerdicts
-from repro.tuning import (
-    TICK_ENGINES,
-    ShapeInfo,
-    choose_tick_engine,
-    choose_tick_plan,
-    estimate_tick_us,
-    tick_work_terms,
-)
 
 P = 3
 
@@ -67,12 +59,10 @@ def test_fused_tick_dispatches_constant(tick_setup):
     boundary + full drain inside) must count the same."""
     eng, tr, stream = tick_setup
     # shallow ticks: stream order, small tick => few ranks
-    srv = FlowTableServer(eng, n_buckets=64, bucket_size=8,
-                          tick_engine="fused")
+    srv = FlowTableServer(eng, n_buckets=64, bucket_size=8)
     shallow = _dispatch_deltas(srv, stream.ticks(64))
     # deep ticks: whole flows per tick => rank depth = flow length
-    srv2 = FlowTableServer(eng, n_buckets=64, bucket_size=8,
-                           tick_engine="fused")
+    srv2 = FlowTableServer(eng, n_buckets=64, bucket_size=8)
     deep = _dispatch_deltas(srv2, _whole_flow_ticks(tr, 16))
     assert max(shallow) <= 2 and max(deep) <= 2
     # identical bound on wildly different tick shapes — O(1) dispatches
@@ -80,29 +70,12 @@ def test_fused_tick_dispatches_constant(tick_setup):
     assert set(shallow) | set(deep) <= {1, 2}
 
 
-def test_legacy_tick_dispatches_grow_with_ranks(tick_setup):
-    """The baseline the fused engine replaces: per-rank fold dispatches
-    plus per-drain-round hop dispatches, so whole-flow ticks cost far
-    more calls than shallow ticks — the O(ranks + drains) shape the
-    cost model's legacy branch charges for."""
-    eng, tr, stream = tick_setup
-    srv = FlowTableServer(eng, n_buckets=64, bucket_size=8,
-                          tick_engine="legacy")
-    shallow = _dispatch_deltas(srv, stream.ticks(64))
-    srv2 = FlowTableServer(eng, n_buckets=64, bucket_size=8,
-                           tick_engine="legacy")
-    deep = _dispatch_deltas(srv2, _whole_flow_ticks(tr, 16))
-    assert max(deep) > max(shallow)
-    assert max(deep) > 2 * max(1, min(shallow))
-
-
 def test_fused_tick_dispatches_independent_of_drain_rounds(tick_setup):
     """Flows shorter than P packets drain multiple empty trailing
     windows in one tick; the fused engine's in-jit while_loop keeps the
     dispatch count at <= 2 anyway."""
     eng, _, _ = tick_setup
-    srv = FlowTableServer(eng, n_buckets=8, bucket_size=4,
-                          tick_engine="fused")
+    srv = FlowTableServer(eng, n_buckets=8, bucket_size=4)
     # single-packet flows: window [0,1) completes on the only packet and
     # partitions 1..P-1 are all empty => P-1 drain rounds inside the jit
     from repro.core.features import PKT_NFIELDS
@@ -116,130 +89,69 @@ def test_fused_tick_dispatches_independent_of_drain_rounds(tick_setup):
     assert v.n_flows == 12  # every flow drained to a verdict in-tick
 
 
-# ---------------------------------------------------------------------------
-# cost model: tick-shape terms route the engines
-# ---------------------------------------------------------------------------
-def _shape(eng, B=512):
-    return ShapeInfo.from_engine(eng, None, B=B, W=1)
-
-
-def test_tick_work_terms_shapes(tick_setup):
+@pytest.mark.parametrize("removed", ["auto", "legacy"])
+def test_server_rejects_removed_tick_engines(tick_setup, removed):
+    """One tick engine: the keyword accepts its name alone, and the
+    removed router and per-rank engine raise instead of falling
+    through."""
     eng, _, _ = tick_setup
-    shape = _shape(eng)
-    from repro.tuning import candidate_plans
-    plan = candidate_plans(shape, compact=False)[0]
-    from repro.tuning.costmodel import TERMS
-    t = {name: i for i, name in enumerate(TERMS)}
-    legacy = tick_work_terms(shape, plan, ranks=8, tick_engine="legacy")
-    fused = tick_work_terms(shape, plan, ranks=8, tick_engine="fused")
-    # legacy pays one call per rank + hop and one sync per hop round;
-    # fused pays a constant call+sync budget
-    assert legacy[t["call"]] > fused[t["call"]]
-    assert legacy[t["sync"]] > fused[t["sync"]]
-    assert fused[t["call"]] == pytest.approx(2.0)
-    assert fused[t["sync"]] == pytest.approx(1.0)
-    with pytest.raises(ValueError):
-        tick_work_terms(shape, plan, tick_engine="looped")
-
-
-def test_tick_estimate_scaling(tick_setup):
-    """Legacy's estimate must grow with rank depth; fused's dispatch
-    overhead must stay flat (only the fold work term grows)."""
-    eng, _, _ = tick_setup
-    shape = _shape(eng)
-    from repro.tuning import candidate_plans
-    plan = candidate_plans(shape, compact=False)[0]
-    legacy = [estimate_tick_us(shape, plan, ranks=r, tick_engine="legacy")
-              for r in (1, 8, 64)]
-    fused = [estimate_tick_us(shape, plan, ranks=r, tick_engine="fused")
-             for r in (1, 8, 64)]
-    assert legacy[0] < legacy[1] < legacy[2]
-    # dispatch overhead: the fused/legacy gap widens with rank count
-    assert (legacy[2] - fused[2]) > (legacy[0] - fused[0])
-    assert all(f < l for f, l in zip(fused, legacy))
-
-
-def test_choose_tick_engine_prefers_fused_on_cpu(tick_setup):
-    """On CPU, dispatch overhead dominates — auto must route fused for
-    any realistic rank depth, which is what tick_engine='auto' uses."""
-    eng, _, _ = tick_setup
-    shape = _shape(eng)
-    for ranks in (1, 4, 32):
-        assert choose_tick_engine(shape, ranks=ranks) == "fused"
-    engine, plan = choose_tick_plan(shape, ranks=4)
-    assert engine in TICK_ENGINES
-    assert plan.backend in ("fused", "pallas")
-
-
-def test_server_auto_resolves_tick_engine(tick_setup):
-    eng, _, stream = tick_setup
-    srv = FlowTableServer(eng, n_buckets=16, bucket_size=4)
-    assert srv.tick_engine in ("fused", "legacy")  # "auto" resolved
-    assert srv.tick_engine == "fused"  # CPU: dispatch overhead dominates
-    with pytest.raises(ValueError):
-        FlowTableServer(eng, tick_engine="warp")
+    FlowTableServer(eng, n_buckets=16, bucket_size=4, tick_engine="fused")
+    with pytest.raises(ValueError, match="fused"):
+        FlowTableServer(eng, n_buckets=16, bucket_size=4,
+                        tick_engine=removed)
 
 
 # ---------------------------------------------------------------------------
-# engines are interchangeable: identical verdicts, identical stats
+# verdicts: the live tick == the batch walk, on either walk backend
 # ---------------------------------------------------------------------------
+def _assert_matches_run(v, want, fids=None):
+    """Each verdict equals ``Engine.run``'s for its flow, once per
+    flow; ``fids``, if given, is the set of flows that must have one."""
+    fid = np.asarray(v.flow_id)
+    assert np.unique(fid).size == fid.size             # one verdict a flow
+    if fids is not None:
+        assert set(map(int, fid)) == set(map(int, fids))
+    np.testing.assert_array_equal(v.labels, np.asarray(want.labels)[fid])
+    np.testing.assert_array_equal(v.recircs, np.asarray(want.recircs)[fid])
+    np.testing.assert_array_equal(
+        v.exit_partition, np.asarray(want.exit_partition)[fid])
+
+
 @pytest.mark.parametrize("impl", ["fused", "pallas"])
-def test_tick_engines_bit_identical(tick_setup, impl):
+def test_fused_tick_matches_engine_run(tick_setup, impl):
     eng, tr, stream = tick_setup
-    outs = {}
-    for te in ("fused", "legacy"):
-        srv = FlowTableServer(
-            eng, n_buckets=32, bucket_size=4, tick_engine=te,
-            options=EngineOptions(impl=impl))
-        parts = [srv.ingest(b) for b in stream.ticks(97)]
-        parts.append(srv.flush())
-        outs[te] = (StreamVerdicts.concat(parts), srv.stats)
-    a, sa = outs["fused"]
-    b, sb = outs["legacy"]
-    oa, ob = np.argsort(a.flow_id), np.argsort(b.flow_id)
-    np.testing.assert_array_equal(a.flow_id[oa], b.flow_id[ob])
-    np.testing.assert_array_equal(a.labels[oa], b.labels[ob])
-    np.testing.assert_array_equal(a.recircs[oa], b.recircs[ob])
-    np.testing.assert_array_equal(a.exit_partition[oa],
-                                  b.exit_partition[ob])
-    # same admission story: EVERY stats field except the engine-dependent
-    # ones (device calls and copies, the engines' whole difference)
-    # agrees, the hash probes included
-    from repro.serve import ServerStats
-    for f in ServerStats.FIELDS:
-        if f in ServerStats.ENGINE_DEPENDENT:
-            continue
-        assert getattr(sa, f) == getattr(sb, f), f
-    assert sa.dispatches < sb.dispatches  # the whole point
+    want = eng.run(window_packets(tr, P), with_trace=False)
+    srv = FlowTableServer(eng, n_buckets=32, bucket_size=4,
+                          options=EngineOptions(impl=impl))
+    parts = [srv.ingest(b) for b in stream.ticks(97)]
+    parts.append(srv.flush())
+    _assert_matches_run(StreamVerdicts.concat(parts), want,
+                        range(tr.n_flows))
+    assert srv.stats.flows_seen == srv.stats.verdicts == tr.n_flows
 
 
 def test_tick_engines_stats_agree_under_spill_and_timeout(tick_setup):
     """The stats-drift audit bar: a tiny table (constant spill traffic)
     plus an aggressive timeout (eviction sentinels) exercises every
-    counter-update path — fused and legacy must still agree on all
-    stats fields, including the spill-run dispatches both engines now
-    count identically."""
+    counter-update path.  The counters agree with the verdicts emitted,
+    and every verdict that is not a timeout sentinel equals
+    ``Engine.run``'s."""
     eng, tr, stream = tick_setup
-    from repro.serve import ServerStats
-    outs = {}
-    for te in ("fused", "legacy"):
-        srv = FlowTableServer(eng, n_buckets=2, bucket_size=2,
-                              tick_engine=te, timeout=0.005)
-        parts = [srv.ingest(b) for b in stream.ticks(131)]
-        parts.append(srv.flush())
-        outs[te] = (StreamVerdicts.concat(parts), srv.stats)
-    a, sa = outs["fused"]
-    b, sb = outs["legacy"]
+    want = eng.run(window_packets(tr, P), with_trace=False)
+    srv = FlowTableServer(eng, n_buckets=2, bucket_size=2, timeout=0.005)
+    parts = [srv.ingest(b) for b in stream.ticks(131)]
+    parts.append(srv.flush())
+    v = StreamVerdicts.concat(parts)
+    sa = srv.stats
     assert sa.spilled > 0          # the tiny table forced the host path
     assert sa.evicted > 0          # the timeout fired
-    oa, ob = np.argsort(a.flow_id), np.argsort(b.flow_id)
-    np.testing.assert_array_equal(a.flow_id[oa], b.flow_id[ob])
-    np.testing.assert_array_equal(a.labels[oa], b.labels[ob])
-    for f in ServerStats.FIELDS:
-        if f in ServerStats.ENGINE_DEPENDENT:
-            continue
-        assert getattr(sa, f) == getattr(sb, f), f
-    assert sa.dispatches < sb.dispatches
+    assert sa.verdicts == v.n_flows == sa.flows_seen == tr.n_flows
+    assert v.n_unterminated >= sa.evicted
+    exited = np.asarray(v.exit_partition) >= 0
+    assert exited.any()
+    _assert_matches_run(StreamVerdicts(
+        v.flow_id[exited], v.labels[exited], v.recircs[exited],
+        v.exit_partition[exited]), want)
     # a placed key examines one bucket or more, a refused key all 2
     assert sa.insert_probes >= sa.flows_seen - sa.spilled + 2 * sa.spilled
 
@@ -253,8 +165,7 @@ def test_fused_tick_copies_five_verdict_rows_per_column(tick_setup):
     flows padded to the power-of-two ladder (floor 64), however large
     the table; nothing else is copied while no flow spills."""
     eng, tr, stream = tick_setup
-    srv = FlowTableServer(eng, n_buckets=64, bucket_size=8,
-                          tick_engine="fused")
+    srv = FlowTableServer(eng, n_buckets=64, bucket_size=8)
     done = set()
     for b in stream.ticks(97):
         live = np.setdiff1d(np.unique(b.flow_id), list(done))
@@ -272,39 +183,43 @@ def test_fused_tick_copies_five_verdict_rows_per_column(tick_setup):
 
 @pytest.fixture(scope="module")
 def probe_runs(tick_setup):
-    """The same stream through an 8 x 4 table on each tick engine:
-    (stats per engine, the unique flow ids summed over its ticks)."""
+    """The same stream through a 16 x 4 table on each walk backend:
+    (stats per backend, the unique flow ids summed over its ticks).
+    Buckets overflow into their neighbours but no flow spills: the
+    spill walk compiles per batch shape, slow on interpreted Pallas,
+    and ``test_tick_engines_stats_agree_under_spill_and_timeout``
+    counts the probes of refused keys."""
     eng, tr, stream = tick_setup
     ticks = list(stream.ticks(97))
     stats = {}
-    for te in ("fused", "legacy"):
-        srv = FlowTableServer(eng, n_buckets=8, bucket_size=4,
-                              tick_engine=te)
+    for impl in ("fused", "pallas"):
+        srv = FlowTableServer(eng, n_buckets=16, bucket_size=4,
+                              options=EngineOptions(impl=impl))
         for b in ticks:
             srv.ingest(b)
-        stats[te] = srv.stats
+        stats[impl] = srv.stats
     n_keys = sum(np.unique(np.asarray(b.flow_id)).size for b in ticks)
     return stats, n_keys
 
 
 def test_insert_probes_agree_across_engines(probe_runs):
     """The hash probes depend on admission alone: the same stream gives
-    the same count in both engines, at least one bucket per placed flow
-    and more once buckets overflow into their neighbours."""
+    the same count on both walk backends, at least one bucket per
+    placed flow and more once buckets overflow into their neighbours."""
     stats, _ = probe_runs
-    sa, sb = stats["fused"], stats["legacy"]
+    sa, sb = stats["fused"], stats["pallas"]
     assert sa.insert_probes == sb.insert_probes
     assert sa.insert_probes > sa.flows_seen - sa.spilled
-    assert sa.d2h_bytes != sb.d2h_bytes     # engine-dependent, by design
+    assert sa.as_dict() == sb.as_dict()   # no counter reads the backend
 
 
 def test_lookup_probes_agree_across_engines(probe_runs):
     """Each tick looks its unique flow ids up once; the buckets those
-    lookups examine depend on admission alone, so both engines count
-    the same, at least one bucket per key and more once keys have
+    lookups examine depend on admission alone, so both walk backends
+    count the same, at least one bucket per key and more once keys have
     overflowed their home buckets."""
     stats, n_keys = probe_runs
-    sa, sb = stats["fused"], stats["legacy"]
+    sa, sb = stats["fused"], stats["pallas"]
     assert sa.lookup_keys == sb.lookup_keys == n_keys
     assert sa.lookup_probes == sb.lookup_probes > sa.lookup_keys
     assert sa.registry.counter("serve_lookup_probes_total").value == \
@@ -364,67 +279,46 @@ def _parity_case(name, tr):
     return sd, list(stream.ticks(61)), dict(n_buckets=64)
 
 
+@pytest.mark.parametrize("impl", ["fused", "pallas"])
 @pytest.mark.parametrize("case", ["whole_flows", "late_packets",
                                   "recycled_slot", "short_flows"])
-def test_working_set_tick_parity(tick_setup, case):
-    """The fused tick gives the verdicts of the legacy engine and of
-    ``Engine.run`` on the rebuilt windows, flow for flow, and a retired
-    row holds no packet past its verdict."""
+def test_working_set_tick_parity(tick_setup, case, impl):
+    """The tick gives the verdicts of ``Engine.run`` on the rebuilt
+    windows, flow for flow, on either walk backend, and a retired row
+    holds no packet past its verdict."""
     eng, tr, _ = tick_setup
     ds, ticks, table = _parity_case(case, tr)
     table.setdefault("bucket_size", 4)
     want = eng.run(window_packets(ds, P), with_trace=False)
-    got = {}
-    for te in ("fused", "legacy"):
-        srv = FlowTableServer(eng, tick_engine=te, **table)
-        parts = []
-        for b in ticks:
-            parts.append(srv.ingest(b))
-            if te == "fused":
-                # a finished flow's row stops where its verdict came:
-                # packets past it never fold (the retired guard)
-                st = srv._tstate
-                done = np.asarray(st.retired) == 1
-                np.testing.assert_array_equal(
-                    np.asarray(st.pkts_seen)[done],
-                    np.asarray(st.win_hi)[done])
-        parts.append(srv.flush())
-        v = StreamVerdicts.concat(parts)
-        fid = np.asarray(v.flow_id)
-        assert np.unique(fid).size == fid.size        # one verdict a flow
-        got[te] = v
-        np.testing.assert_array_equal(
-            v.labels, np.asarray(want.labels)[fid])
-        np.testing.assert_array_equal(
-            v.recircs, np.asarray(want.recircs)[fid])
-        np.testing.assert_array_equal(
-            v.exit_partition, np.asarray(want.exit_partition)[fid])
-    np.testing.assert_array_equal(np.sort(got["fused"].flow_id),
-                                  np.sort(got["legacy"].flow_id))
+    srv = FlowTableServer(eng, options=EngineOptions(impl=impl), **table)
+    parts = []
+    for b in ticks:
+        parts.append(srv.ingest(b))
+        # a finished flow's row stops where its verdict came: packets
+        # past it never fold (the retired guard)
+        st = srv._tstate
+        done = np.asarray(st.retired) == 1
+        np.testing.assert_array_equal(np.asarray(st.pkts_seen)[done],
+                                      np.asarray(st.win_hi)[done])
+    parts.append(srv.flush())
     served = {int(f) for b in ticks for f in b.flow_id}
-    assert set(map(int, got["fused"].flow_id)) == served
+    _assert_matches_run(StreamVerdicts.concat(parts), want, served)
 
 
 def test_recircs_mirror_tracks_device_under_spill_and_timeout(tick_setup):
     """The host ``_recircs`` mirror, written only at each tick's
     columns, equals the device's ``recircs`` at every resident slot
-    after every tick, and the legacy engine's host array there, on a
-    tiny table (spill) with an aggressive timeout (evictions)."""
+    after every tick, on a tiny table (spill) with an aggressive
+    timeout (evictions)."""
     eng, _, stream = tick_setup
-    kw = dict(n_buckets=2, bucket_size=2, timeout=0.005)
-    fused = FlowTableServer(eng, tick_engine="fused", **kw)
-    legacy = FlowTableServer(eng, tick_engine="legacy", **kw)
-    N = fused.table.capacity
+    srv = FlowTableServer(eng, n_buckets=2, bucket_size=2, timeout=0.005)
+    N = srv.table.capacity
     n_rec = 0
     for b in itertools.islice(stream.ticks(131), 24):
-        fused.ingest(b)
-        legacy.ingest(b)
-        res = fused.table.key >= 0
-        np.testing.assert_array_equal(res, legacy.table.key >= 0)
-        dev = np.asarray(fused._tstate.recircs)[:N]
-        np.testing.assert_array_equal(fused._recircs[res], dev[res])
-        np.testing.assert_array_equal(fused._recircs[res],
-                                      legacy._recircs[res])
+        srv.ingest(b)
+        res = srv.table.key >= 0
+        dev = np.asarray(srv._tstate.recircs)[:N]
+        np.testing.assert_array_equal(srv._recircs[res], dev[res])
         n_rec += int(dev[res].sum())
-    assert fused.stats.spilled > 0 and fused.stats.evicted > 0
+    assert srv.stats.spilled > 0 and srv.stats.evicted > 0
     assert n_rec > 0                   # resident flows did recirculate
